@@ -82,6 +82,7 @@ import numpy as np
 
 import repro.configs as C
 from repro.core.swis import QuantConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import params as pp
 from repro.models.model import Model
 from repro.serve import ContinuousBatchingEngine, EngineConfig, SamplingParams
@@ -427,6 +428,7 @@ def main():
     args = ap.parse_args()
     if args.update_baseline and not args.check_baseline:
         ap.error("--update-baseline needs --check-baseline PATH to write")
+    enable_compile_cache()
 
     cfg = C.get_smoke(args.arch).replace(compute_dtype="float32")
     params = pp.init_params(Model(cfg).build(), jax.random.key(0))

@@ -3,7 +3,8 @@
 1. Quantize a weight matrix with SWIS / SWIS-C / truncation and compare RMSE
    (paper Table 1).
 2. Pack to the compressed bit-plane format and run the dequant-in-kernel
-   matmul (Pallas interpret mode) against the dense result.
+   matmul (the compiled Pallas kernel on a TPU, interpret mode elsewhere)
+   against the dense result.
 3. Quantize a whole model (PTQ) and compare task accuracy vs truncation.
 
 Run:  PYTHONPATH=src python examples/quickstart.py
@@ -42,7 +43,8 @@ def main():
     print(f"  compression: {pw.compression_ratio:.2f}x vs int8 "
           f"({pw.stored_bits / 8 / 1024:.1f} KiB packed)")
     x = jnp.asarray(rng.normal(0, 1, (16, 256)).astype(np.float32))
-    y_packed = ops.swis_matmul(x, pw, use_pallas=True, interpret=True)
+    y_packed = ops.swis_matmul(x, pw, use_pallas=True,
+                               interpret=jax.default_backend() != "tpu")
     y_dense = x @ qw.qweights
     err = float(jnp.max(jnp.abs(y_packed - y_dense))
                 / jnp.max(jnp.abs(y_dense)))

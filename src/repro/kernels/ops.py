@@ -1,9 +1,9 @@
 """Jit'd public wrappers around the SWIS Pallas kernels.
 
-``swis_matmul`` dispatches between the Pallas kernel (TPU target /
-interpret-mode validation) and the pure-jnp reference path (CPU + dry-run:
-identical math and identical *packed* HBM operands, so cost_analysis sees the
-compressed weight bytes either way).
+``swis_matmul`` dispatches between the Pallas kernel (compiled for the TPU;
+``interpret=True`` for CPU validation) and the pure-jnp reference path
+(CPU + dry-run: identical math and identical *packed* HBM operands, so
+cost_analysis sees the compressed weight bytes either way).
 
 A custom VJP makes the packed matmul differentiable w.r.t. the activations
 (weights are frozen post-PTQ), so packed serving graphs can still be
@@ -21,35 +21,15 @@ from repro.kernels import ref as _ref
 from repro.kernels.swis_matmul import swis_matmul_packed
 
 
-def _pick_tiles(m: int, k: int, n: int, group: int):
-    def largest(div, cands):
-        for c in cands:
-            if div % c == 0:
-                return c
-        return div
-
-    bm = largest(m, (128, 64, 32, 16, 8, 4, 2, 1))
-    bn = largest(n, (128, 256, 64, 32))
-    bk_base = 512
-    while bk_base > 32 and (k % bk_base or bk_base % group):
-        bk_base //= 2
-    bk = bk_base if (k % bk_base == 0 and bk_base % group == 0) else k
-    return bm, bn, bk
-
-
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
 def _matmul(x, planes, static):
     group, n_shifts, use_pallas, interpret, consecutive, keep_slices = static
     sign_plane, mask_planes, shifts, scale = planes
     if use_pallas:
-        m, k = x.shape
-        n = sign_plane.shape[1]
-        bm, bn, bk = _pick_tiles(m, k, n, group)
         return swis_matmul_packed(
             x, sign_plane, mask_planes, shifts, scale,
-            n_shifts=n_shifts, group=group, bm=bm, bn=bn, bk=bk,
-            interpret=interpret, consecutive=consecutive,
-            keep_slices=keep_slices,
+            n_shifts=n_shifts, group=group, interpret=interpret,
+            consecutive=consecutive, keep_slices=keep_slices,
         )
     return _ref.swis_matmul_ref(
         x, sign_plane, mask_planes, shifts, scale, group=group,
@@ -80,7 +60,7 @@ def swis_matmul(
     pw: PackedWeight,
     *,
     use_pallas: bool = False,
-    interpret: bool = True,
+    interpret: bool = False,
     keep_slices=None,
 ) -> jnp.ndarray:
     """``x @ dequant(pw)`` for arbitrary-rank ``x`` (matmul over last axis).

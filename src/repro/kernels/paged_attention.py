@@ -6,12 +6,13 @@ physical-block KV arena (``repro.serve.kv_cache.SlotKVCache`` block mode)
 (``models/attention.py``) re-builds an O(B * n_logical_blocks * block_size
 * Hkv * Dh) contiguous view of every slot's cache each step — exactly the
 copy paged attention exists to avoid. Here the grid iterates (slot,
-kv-head, logical block); each program reads ``block_tables[slot, j]`` from
-SMEM (scalar prefetch, so the index is known before the body runs) and
-DMAs only that physical K/V block into VMEM. The softmax is accumulated
-online across the block axis (flash-decoding style): running max /
-denominator / weighted-V scratch persists across the innermost grid
-dimension and the output block is finalized on the last logical block.
+logical block); each program reads ``block_tables[slot, j]`` from SMEM
+(scalar prefetch, so the index is known before the body runs) and DMAs
+only that physical K/V block, all kv-heads at once, into VMEM. The
+softmax is accumulated online across the block axis (flash-decoding
+style): running max / denominator / weighted-V scratch persists across
+the innermost grid dimension and the output block is finalized on the
+last logical block.
 
 Rows may carry more than one query token (the fused mixed step batches
 one-token decode rows together with an S-token prefill chunk row): row
@@ -32,8 +33,9 @@ Masking contract (identical to the gather path):
 Two implementations behind one wrapper, both bit-identical in masking and
 accumulation order:
 
-  * ``impl="pallas"`` — the kernel above (``interpret=True`` runs the body
-    in Python for CPU validation, same contract as ``swis_matmul_packed``);
+  * ``impl="pallas"`` — the kernel above, compiled for the TPU
+    (``impl="pallas_interpret"`` runs the same body in Python, for CPU
+    validation only);
   * ``impl="xla"`` — a ``lax.scan`` over logical blocks gathering one
     (B, block_size) K/V slab per step. Working set is O(B * block_size),
     never O(B * n_blocks * block_size); this is the serving path on
@@ -47,11 +49,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # pltpu is importable on CPU builds; guard anyway for slim installs
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover - exercised only on partial installs
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 
 def mask_value(dtype) -> float:
@@ -67,14 +65,14 @@ def _paged_decode_kernel(
     tables_ref,  # (B, nb) int32, SMEM scalar prefetch
     qpos_ref,  # (B,) int32, SMEM scalar prefetch: row's first query pos
     qlens_ref,  # (B,) int32, SMEM scalar prefetch: valid queries per row
-    q_ref,  # (1, 1, Sq*G, Dh) this slot+kv-head's queries, Sq-major
-    k_ref,  # (1, bs, 1, Dh) the *physical* block tables[b, j] points at
-    v_ref,  # (1, bs, 1, Dh)
-    pos_ref,  # (1, bs) int32 position plane of that physical block
-    o_ref,  # (1, 1, Sq*G, Dh) output, revisited across the block axis
-    m_ref,  # (Sq*G, 1) f32 scratch: running max
-    l_ref,  # (Sq*G, 1) f32 scratch: running denominator
-    acc_ref,  # (Sq*G, Dh) f32 scratch: running weighted V
+    q_ref,  # (1, Hkv, Sq*G, Dh) this slot's queries, Sq-major per kv-head
+    k_ref,  # (1, bs, Hkv, Dh) the *physical* block tables[b, j] points at
+    v_ref,  # (1, bs, Hkv, Dh)
+    pos_ref,  # (1, 1, bs) int32 position plane of that physical block
+    o_ref,  # (1, Hkv, Sq*G, Dh) output, revisited across the block axis
+    m_ref,  # (Hkv, Sq*G, 1) f32 scratch: running max
+    l_ref,  # (Hkv, Sq*G, 1) f32 scratch: running denominator
+    acc_ref,  # (Hkv, Sq*G, Dh) f32 scratch: running weighted V
     *,
     nb: int,
     sq: int,
@@ -82,7 +80,7 @@ def _paged_decode_kernel(
     window: Optional[int],
 ):
     b = pl.program_id(0)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
     neg = mask_value(jnp.float32)
 
     @pl.when(j == 0)
@@ -91,79 +89,83 @@ def _paged_decode_kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    dh = q_ref.shape[-1]
-    sg = q_ref.shape[-2]
+    hkv, sg, dh = q_ref.shape[1:]
     g = sg // sq
     bs = k_ref.shape[1]
-    q = q_ref[0, 0].astype(jnp.float32) * (dh ** -0.5)  # (Sq*G, Dh)
-    k = k_ref[0, :, 0].astype(jnp.float32)  # (bs, Dh)
-    v = v_ref[0, :, 0].astype(jnp.float32)
-    s = jax.lax.dot_general(  # (Sq*G, bs)
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
-
-    pos = pos_ref[0]  # (bs,)
+    pos = pos_ref[0]  # (1, bs)
     qp = qpos_ref[b]
     ql = qlens_ref[b]
     # per-score query index: score row i*G+g' belongs to query i, whose
     # absolute position is qp + i (TPU needs >= 2-D iota)
-    qi = jax.lax.broadcasted_iota(jnp.int32, (sq, g, bs), 0)
-    valid = jnp.broadcast_to(pos[None, None, :] >= 0, (sq, g, bs))
+    qi = jax.lax.broadcasted_iota(jnp.int32, (sq, g, bs), 0).reshape(sg, bs)
+    valid = jnp.broadcast_to(pos >= 0, (sg, bs))
     # logical blocks parked on the trash block are invalid by definition
     valid &= tables_ref[b, j] != 0
     # queries past the row's count are padding: mask them wholesale so
     # their output rows finalize to exact zeros
     valid &= qi < ql
     if causal:
-        valid &= pos[None, None, :] <= qp + qi
+        valid &= pos <= qp + qi
     if window is not None:
-        valid &= pos[None, None, :] > qp + qi - window
-    s = jnp.where(valid.reshape(sg, bs), s, neg)
+        valid &= pos > qp + qi - window
 
-    m_prev = m_ref[...]  # (Sq*G, 1)
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new)  # (Sq*G, bs)
-    m_ref[...] = m_new
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    # one DMA brings every kv-head of the block; the heads share the mask
+    for h in range(hkv):
+        q = q_ref[0, h].astype(jnp.float32) * (dh ** -0.5)  # (Sq*G, Dh)
+        k = k_ref[0, :, h, :].astype(jnp.float32)  # (bs, Dh)
+        v = v_ref[0, :, h, :].astype(jnp.float32)
+        s = jax.lax.dot_general(  # (Sq*G, bs)
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        s = jnp.where(valid, s, neg)
+        m_prev = m_ref[h]  # (Sq*G, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)  # (Sq*G, bs)
+        m_ref[h] = m_new
+        l_ref[h] = l_ref[h] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
     @pl.when(j == nb - 1)
     def _finish():
         out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = out.astype(o_ref.dtype)
+        o_ref[0] = out.astype(o_ref.dtype)
 
 
 @functools.partial(
     jax.jit, static_argnames=("sq", "causal", "window", "interpret"))
 def _paged_attention_pallas(q4, k_arena, v_arena, pos_arena, block_tables,
                             q_pos, q_lens, *, sq, causal, window, interpret):
-    """q4: (B, Hkv, Sq*G, Dh) -> (B, Hkv, Sq*G, Dh) float32."""
+    """q4: (B, Hkv, Sq*G, Dh) -> (B, Hkv, Sq*G, Dh) float32.
+
+    The grid is (slot, logical block). Every block spec covers its array's
+    last two dims whole (the TPU block rule): a program takes all kv-heads
+    of one physical block, and the pos plane is viewed as (n_blocks, 1, bs).
+    """
     b, hkv, sg, dh = q4.shape
-    bs = k_arena.shape[1]
+    n_blocks, bs = pos_arena.shape
     nb = block_tables.shape[1]
-    if pltpu is None:  # pragma: no cover
-        raise RuntimeError("pallas TPU frontend unavailable")
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(b, hkv, nb),
+        grid=(b, nb),
         in_specs=[
-            pl.BlockSpec((1, 1, sg, dh),
-                         lambda bi, h, j, t, qp, ql: (bi, h, 0, 0)),
-            pl.BlockSpec((1, bs, 1, dh),
-                         lambda bi, h, j, t, qp, ql: (t[bi, j], 0, h, 0)),
-            pl.BlockSpec((1, bs, 1, dh),
-                         lambda bi, h, j, t, qp, ql: (t[bi, j], 0, h, 0)),
-            pl.BlockSpec((1, bs), lambda bi, h, j, t, qp, ql: (t[bi, j], 0)),
+            pl.BlockSpec((1, hkv, sg, dh),
+                         lambda bi, j, t, qp, ql: (bi, 0, 0, 0)),
+            pl.BlockSpec((1, bs, hkv, dh),
+                         lambda bi, j, t, qp, ql: (t[bi, j], 0, 0, 0)),
+            pl.BlockSpec((1, bs, hkv, dh),
+                         lambda bi, j, t, qp, ql: (t[bi, j], 0, 0, 0)),
+            pl.BlockSpec((1, 1, bs),
+                         lambda bi, j, t, qp, ql: (t[bi, j], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, sg, dh),
-                               lambda bi, h, j, t, qp, ql: (bi, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, hkv, sg, dh),
+                               lambda bi, j, t, qp, ql: (bi, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((sg, 1), jnp.float32),
-            pltpu.VMEM((sg, 1), jnp.float32),
-            pltpu.VMEM((sg, dh), jnp.float32),
+            pltpu.VMEM((hkv, sg, 1), jnp.float32),
+            pltpu.VMEM((hkv, sg, 1), jnp.float32),
+            pltpu.VMEM((hkv, sg, dh), jnp.float32),
         ],
     )
     kernel = functools.partial(_paged_decode_kernel, nb=nb, sq=sq,
@@ -174,7 +176,8 @@ def _paged_attention_pallas(q4, k_arena, v_arena, pos_arena, block_tables,
         out_shape=jax.ShapeDtypeStruct((b, hkv, sg, dh), jnp.float32),
         interpret=interpret,
     )(jnp.asarray(block_tables, jnp.int32), jnp.asarray(q_pos, jnp.int32),
-      jnp.asarray(q_lens, jnp.int32), q4, k_arena, v_arena, pos_arena)
+      jnp.asarray(q_lens, jnp.int32), q4, k_arena, v_arena,
+      pos_arena.reshape(n_blocks, 1, bs))
 
 
 def _paged_attention_xla(q4, k_arena, v_arena, pos_arena, block_tables,

@@ -16,6 +16,7 @@ any other import, including jax). Results are cached as JSON per cell under
 import argparse
 import dataclasses
 import json
+import sys
 import time
 import traceback
 from typing import Any, Dict, Optional
@@ -299,15 +300,15 @@ def cell_name(arch: str, shape: str, mesh_kind: str, quant: str) -> str:
 
 
 def run_cells(cells, out_dir: str, quant: str = "qat", force: bool = False):
+    """Lower and compile each cell; returns the names of the cells that
+    failed."""
     os.makedirs(out_dir, exist_ok=True)
     meshes = {}
-    results = []
+    failed = []
     for arch_id, shape_name, mesh_kind in cells:
         name = cell_name(arch_id, shape_name, mesh_kind, quant)
         path = os.path.join(out_dir, name + ".json")
         if os.path.exists(path) and not force:
-            with open(path) as f:
-                results.append(json.load(f))
             print(f"[skip] {name}")
             continue
         cfg = C.get_config(arch_id)
@@ -334,13 +335,13 @@ def run_cells(cells, out_dir: str, quant: str = "qat", force: bool = False):
                   f"compute={r['compute_s']:.4f}s memory={r['memory_s']:.4f}s "
                   f"coll={r['collective_s']:.4f}s -> {r['bottleneck']}",
                   flush=True)
-            results.append(rec)
         except Exception as e:
             print(f"  FAIL {type(e).__name__}: {e}")
             traceback.print_exc()
             with open(os.path.join(out_dir, name + ".err"), "w") as f:
                 f.write(traceback.format_exc())
-    return results
+            failed.append(name)
+    return failed
 
 
 def main():
@@ -364,7 +365,10 @@ def main():
         archs = [args.arch] if args.arch else list(C.ARCH_IDS)
         shapes = [args.shape] if args.shape else list(SHAPES)
     cells = [(a, s, m) for a in archs for s in shapes for m in mesh_kinds]
-    run_cells(cells, args.out, quant=args.quant, force=args.force)
+    failed = run_cells(cells, args.out, quant=args.quant, force=args.force)
+    if failed:
+        sys.exit(f"{len(failed)} of {len(cells)} cells failed: "
+                 f"{', '.join(failed)}")
 
 
 if __name__ == "__main__":

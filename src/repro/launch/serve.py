@@ -28,6 +28,7 @@ import numpy as np
 
 import repro.configs as C
 from repro.core.swis import QuantConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import params as pp
 from repro.models.model import Model
 from repro.serve import (ContinuousBatchingEngine, DecodeEngine,
@@ -95,9 +96,9 @@ def main():
                     help="export the trace: *.json -> Chrome trace-event "
                          "JSON (load in Perfetto), else lifecycle JSONL")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = C.get_smoke(args.arch) if args.smoke else C.get_config(args.arch)
-    cfg = cfg.replace(compute_dtype="float32")  # CPU demo
     model = Model(cfg)
     if args.ckpt:
         from repro.checkpoint import CheckpointManager

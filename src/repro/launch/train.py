@@ -14,11 +14,11 @@ from __future__ import annotations
 import argparse
 import json
 
-import jax
-
 import repro.configs as C
 from repro.configs.base import QuantPolicy
 from repro.core.swis import QuantConfig
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.train.loop import Trainer
 
 
@@ -41,6 +41,7 @@ def main():
     ap.add_argument("--mesh-data", type=int, default=0)
     ap.add_argument("--mesh-model", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = C.get_smoke(args.arch) if args.smoke else C.get_config(args.arch)
     if args.quant != "none":
@@ -50,8 +51,7 @@ def main():
             mode="qat"))
     mesh = None
     if args.mesh_data and args.mesh_model:
-        mesh = jax.make_mesh((args.mesh_data, args.mesh_model),
-                             ("data", "model"))
+        mesh = make_mesh((args.mesh_data, args.mesh_model), ("data", "model"))
 
     tr = Trainer(cfg, seq_len=args.seq, global_batch=args.batch,
                  workdir=args.workdir, total_steps=args.steps,

@@ -17,8 +17,6 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Optional
 
-import jax.numpy as jnp
-
 from repro.core.swis import QuantConfig
 from repro.kernels.paged_attention import VALID_PAGED_IMPLS
 
@@ -34,7 +32,8 @@ class EngineConfig:
       block_size — KV arena block granularity (block mode);
       n_cache_blocks — extra arena blocks beyond the slots' own capacity
         (None: two slots' worth, for cached-but-unreferenced prefixes);
-      cache_dtype — KV storage dtype;
+      cache_dtype — KV storage dtype (None: the model's compute
+        dtype);
       prefix_cache — block arena + radix prefix cache (uniform attention
         families only; the engine falls back to contiguous rows when the
         family's cache is not block-compatible).
@@ -77,7 +76,7 @@ class EngineConfig:
     # cache
     block_size: int = 8
     n_cache_blocks: Optional[int] = None
-    cache_dtype: Any = jnp.float32
+    cache_dtype: Any = None
     prefix_cache: bool = True
     # prefill
     prefill_chunk: Optional[int] = None

@@ -208,13 +208,14 @@ class ContinuousBatchingEngine:
         # a window-truncated ring could roll real KV out in their favor.
         self.bucket_prompts = config.bucket_prompts and uniform
         self.scheduler = RequestScheduler(n_slots)
+        cache_dtype = jnp.dtype(config.cache_dtype or self.cfg.compute_dtype)
         if config.prefix_cache and uniform:
             bps = -(-max_len // config.block_size)
             extra = (2 * bps if config.n_cache_blocks is None
                      else config.n_cache_blocks)
             n_blocks = n_slots * bps + extra + 1  # +1: trash block
             self.cache = SlotKVCache(self.model, n_slots, max_len,
-                                     config.cache_dtype,
+                                     cache_dtype,
                                      block_size=config.block_size,
                                      n_blocks=n_blocks)
             self.prefix_cache: Optional[RadixPrefixCache] = RadixPrefixCache(
@@ -224,7 +225,7 @@ class ContinuousBatchingEngine:
         else:
             # recurrent / window-truncated caches: contiguous per-slot rows
             self.cache = SlotKVCache(self.model, n_slots, max_len,
-                                     config.cache_dtype)
+                                     cache_dtype)
             self.prefix_cache = None
         if prefill_chunk is not None:
             if self.prefix_cache is None:
@@ -1127,7 +1128,7 @@ class DecodeEngine:
     batch: int = 1
     packed: bool = False
     quant_cfg: Optional[QuantConfig] = None
-    cache_dtype: Any = jnp.float32
+    cache_dtype: Any = None  # None: the model's compute dtype
 
     def __post_init__(self):
         self.cfg, self.params, self.pack_stats = _maybe_pack(
@@ -1137,8 +1138,9 @@ class DecodeEngine:
         self._decode = jax.jit(self.model.decode_step, donate_argnums=(2,))
 
     def new_cache(self):
-        tree = self.model.build_cache(self.batch, self.max_len,
-                                      self.cache_dtype)
+        tree = self.model.build_cache(
+            self.batch, self.max_len,
+            jnp.dtype(self.cache_dtype or self.cfg.compute_dtype))
         return pp.init_params(tree, jax.random.key(0))
 
     def generate(self, prompt: np.ndarray, n_tokens: int,

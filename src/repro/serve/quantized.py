@@ -9,6 +9,7 @@ bytes, which is where the paper's compression lands on TPU.
 """
 from __future__ import annotations
 
+import functools
 from typing import Dict
 
 import jax
@@ -43,6 +44,7 @@ def _eligible(path_keys, arr) -> bool:
     return True
 
 
+@functools.partial(jax.jit, static_argnums=1)  # one compile per (K, C)
 def _pack_matrix(w: jnp.ndarray, qcfg: QuantConfig) -> Dict[str, jnp.ndarray]:
     qw = quantize(jnp.asarray(w, jnp.float32), qcfg)
     pw = packing.pack(qw)

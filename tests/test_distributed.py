@@ -23,8 +23,9 @@ def test_sharded_training_loss_decreases():
 import warnings; warnings.filterwarnings('ignore')
 import jax
 import repro.configs as C
+from repro.launch.mesh import make_mesh
 from repro.train.loop import Trainer
-mesh = jax.make_mesh((2, 4), ('data', 'model'))
+mesh = make_mesh((2, 4), ('data', 'model'))
 tr = Trainer(C.get_smoke('qwen2-moe-a2.7b'), seq_len=32, global_batch=8,
              total_steps=6, warmup=2, peak_lr=5e-3, mesh=mesh)
 o = tr.run(6)
@@ -42,13 +43,14 @@ def test_elastic_remesh_restore():
 import warnings, tempfile, os; warnings.filterwarnings('ignore')
 import jax
 import repro.configs as C
+from repro.launch.mesh import make_mesh
 from repro.train.loop import Trainer
 cfg = C.get_smoke('phi3-mini-3.8b')
 with tempfile.TemporaryDirectory() as d:
-    m1 = jax.make_mesh((2, 4), ('data', 'model'))
+    m1 = make_mesh((2, 4), ('data', 'model'))
     Trainer(cfg, seq_len=32, global_batch=8, total_steps=4, ckpt_every=2,
             warmup=2, mesh=m1, workdir=d).run(4)
-    m2 = jax.make_mesh((4, 2), ('data', 'model'))
+    m2 = make_mesh((4, 2), ('data', 'model'))
     o = Trainer(cfg, seq_len=32, global_batch=8, total_steps=6, ckpt_every=2,
                 warmup=2, mesh=m2, workdir=d).run(6)
     assert len(o['losses']) == 2  # resumed from step 4
@@ -63,12 +65,13 @@ def test_fsdp_zero3_training():
 import warnings, dataclasses; warnings.filterwarnings('ignore')
 import jax
 import repro.configs as C
+from repro.launch.mesh import make_mesh
 from repro.train.loop import Trainer
 cfg = C.get_smoke('deepseek-7b')
 cfg = cfg.replace(parallel=dataclasses.replace(cfg.parallel,
                                                fsdp_params=True,
                                                grad_accum=2))
-mesh = jax.make_mesh((2, 4), ('data', 'model'))
+mesh = make_mesh((2, 4), ('data', 'model'))
 tr = Trainer(cfg, seq_len=32, global_batch=8, total_steps=4, warmup=2,
              peak_lr=5e-3, mesh=mesh)
 o = tr.run(4)
@@ -86,8 +89,9 @@ def test_compressed_allreduce_matches_mean():
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import Mesh, PartitionSpec as PS
 from jax.experimental.shard_map import shard_map
+from repro.launch.mesh import make_mesh
 from repro.optim.compress import compressed_allreduce
-mesh = jax.make_mesh((8,), ('pod',))
+mesh = make_mesh((8,), ('pod',))
 x = jnp.asarray(np.random.default_rng(0).normal(0, 1, (8, 64)).astype(np.float32))
 f = shard_map(lambda s: compressed_allreduce(s, 'pod'), mesh=mesh,
               in_specs=PS('pod'), out_specs=PS('pod'))
@@ -100,14 +104,15 @@ assert rel < 0.02
     assert "REL" in out
 
 
-def test_dryrun_entry_single_cell():
+def test_dryrun_entry_single_cell(tmp_path):
     # the dry-run module itself (512 fake devices) on the cheapest cell
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     r = subprocess.run(
         [sys.executable, "-m", "repro.launch.dryrun", "--arch", "smollm-135m",
          "--shape", "decode_32k", "--mesh", "single", "--out",
-         "/tmp/dryrun_test", "--force"],
+         str(tmp_path), "--force"],
         env=env, timeout=560, capture_output=True, text=True, cwd=REPO)
     assert r.returncode == 0, r.stdout + r.stderr
     assert "ok lower=" in r.stdout
+    assert "FAIL" not in r.stdout, r.stdout

@@ -159,36 +159,48 @@ def test_tile_error_paths(rng, kwargs, match):
 
 
 # ---------------------------------------------------------------------------
-# _pick_tiles: the launcher's tile-shape heuristic
+# pick_tiles: the kernel's tile-shape heuristic
 # ---------------------------------------------------------------------------
 
 
-def test_pick_tiles_divisibility_invariants():
-    from repro.kernels.ops import _pick_tiles
+def _legal(tile, full, align):
+    return tile == full or tile % align == 0
 
-    for m, k, n, group in [(128, 512, 256, 4), (16, 256, 128, 8),
-                           (8, 64, 256, 16), (4, 96, 128, 4)]:
-        bm, bn, bk = _pick_tiles(m, k, n, group)
+
+def test_pick_tiles_divisibility_invariants():
+    from repro.kernels.swis_matmul import pick_tiles
+
+    for m, k, n in [(128, 512, 256), (16, 256, 128), (8, 64, 256),
+                    (4, 96, 128), (8, 576, 1536), (24, 1536, 576),
+                    (8, 8192, 192)]:
+        bm, bn, bk = pick_tiles(m, k, n)
         assert m % bm == 0 and n % bn == 0 and k % bk == 0
-        assert bk % group == 0
+        # the TPU block rule: (8, 128)-aligned or the whole dimension
+        assert _legal(bm, m, 8) and _legal(bn, n, 128)
+        assert _legal(bk, k, 4096)
 
 
 def test_pick_tiles_odd_prime_dims():
-    from repro.kernels.ops import _pick_tiles
+    from repro.kernels.swis_matmul import pick_tiles
 
-    # prime dims: bm degrades to the 1-row candidate, bn/bk fall back to
-    # the full dimension (still valid: whole-axis single tile)
-    bm, bn, bk = _pick_tiles(7, 97, 13, 1)
-    assert (bm, bn, bk) == (1, 13, 97)
+    # prime dims have no aligned divisor: every tile is the whole
+    # dimension (still valid: whole-axis single tile)
+    assert pick_tiles(7, 97, 13) == (7, 13, 97)
 
 
 def test_pick_tiles_group_forces_bk_fallback():
-    from repro.kernels.ops import _pick_tiles
+    from repro.kernels.swis_matmul import pick_tiles, swis_matmul_packed
 
-    # group=3 divides k=96 but no power-of-two candidate, so bk must fall
-    # back to the whole K dimension
-    bm, bn, bk = _pick_tiles(8, 96, 128, 3)
-    assert bk == 96 and bk % 3 == 0
-    # and a group that divides the halved candidates keeps the tile small
-    _, _, bk2 = _pick_tiles(8, 1024, 128, 4)
-    assert bk2 == 512
+    # no multiple of 4096 divides K: bk falls back to the whole K
+    assert pick_tiles(8, 96, 128)[2] == 96
+    assert pick_tiles(8, 576, 128)[2] == 576
+    # one that does keeps the tile aligned
+    assert pick_tiles(8, 8192, 128)[2] == 4096
+    # a group must tile a packed word, so group=3 is refused outright
+    x = jnp.ones((8, 96), jnp.float32)
+    with pytest.raises(ValueError, match="group"):
+        swis_matmul_packed(
+            x, jnp.zeros((3, 128), jnp.uint32),
+            jnp.zeros((2, 3, 128), jnp.uint32),
+            jnp.zeros((32, 128, 1), jnp.uint8), jnp.ones((1, 128)),
+            n_shifts=2, group=3, interpret=True)
