@@ -173,6 +173,7 @@ def _paged_attention_pallas(q4, k_arena, v_arena, pos_arena, block_tables,
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
+        name="_paged_attention_pallas",
         out_shape=jax.ShapeDtypeStruct((b, hkv, sg, dh), jnp.float32),
         interpret=interpret,
     )(jnp.asarray(block_tables, jnp.int32), jnp.asarray(q_pos, jnp.int32),
@@ -264,20 +265,25 @@ def paged_attention_decode(
     # path's full_attention uses, so outputs line up head-for-head. The
     # query axis folds in Sq-major ((q0 heads..., q1 heads...)) so the
     # kernel's score row i*G+g' maps back to query i of head group g'.
-    q4 = (q.reshape(b, s, hkv, g, dh).transpose(0, 2, 1, 3, 4)
-          .reshape(b, hkv, s * g, dh))
+    # the layouts around the kernel are attention work; the Pallas kernel
+    # is traced outside the name scope (a trace finds it by its name)
+    with jax.named_scope("attention"):
+        q4 = (q.reshape(b, s, hkv, g, dh).transpose(0, 2, 1, 3, 4)
+              .reshape(b, hkv, s * g, dh))
     if impl in ("pallas", "pallas_interpret"):
         out = _paged_attention_pallas(
             q4, k_arena, v_arena, pos_arena, block_tables, q_pos, q_lens,
             sq=s, causal=causal, window=window,
             interpret=(impl == "pallas_interpret"))
     elif impl == "xla":
-        out = _paged_attention_xla(
-            q4, k_arena, v_arena, pos_arena, block_tables, q_pos, q_lens,
-            sq=s, causal=causal, window=window)
+        with jax.named_scope("attention"):
+            out = _paged_attention_xla(
+                q4, k_arena, v_arena, pos_arena, block_tables, q_pos,
+                q_lens, sq=s, causal=causal, window=window)
     else:
         raise ValueError(
             f"unknown paged attention impl {impl!r}; valid impls: "
             f"{', '.join(VALID_PAGED_IMPLS)}")
-    return (out.reshape(b, hkv, s, g, dh).transpose(0, 2, 1, 3, 4)
-            .reshape(b, s, h, dh).astype(q.dtype))
+    with jax.named_scope("attention"):
+        return (out.reshape(b, hkv, s, g, dh).transpose(0, 2, 1, 3, 4)
+                .reshape(b, s, h, dh).astype(q.dtype))

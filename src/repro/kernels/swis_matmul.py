@@ -193,6 +193,7 @@ def swis_matmul_packed(
     return pl.pallas_call(
         kernel,
         grid=grid,
+        name="swis_matmul_packed",
         in_specs=[
             pl.BlockSpec((1, _BITS, bm, words),
                          lambda i, j, kk: (kk, 0, i, 0)),

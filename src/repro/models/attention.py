@@ -190,6 +190,12 @@ def attention_apply(
     block table inside this same launch, and attention reads the arena
     through the tables — one dispatch covers the decode batch and a
     prefill chunk with zero host-side commit work afterwards.
+
+    Name scopes in the step programs' metadata: the projections are
+    ``gemm`` (``dense``), the arena writes ``kv_commit``, and the rest
+    ``attention``, but for the Pallas paged kernel's own call, which a
+    device trace finds by the kernel's pinned name: its body is traced
+    under no model scope, which keeps set-up fast on the chip.
     """
     h, hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     b, s, _ = x.shape
@@ -200,17 +206,19 @@ def attention_apply(
     v = _split_heads(dense(p["wv"], kv_src, cfg), hkv, dh)
 
     if use_rope and ctx is None:
-        pos_b = positions if positions.ndim == 2 else positions[None, :]
-        q = rope(q, pos_b, cfg.rope_theta)
-        k = rope(k, pos_b, cfg.rope_theta)
+        with jax.named_scope("attention"):
+            pos_b = positions if positions.ndim == 2 else positions[None, :]
+            q = rope(q, pos_b, cfg.rope_theta)
+            k = rope(k, pos_b, cfg.rope_theta)
 
     new_cache = None
     if ctx is not None:
-        kv_pos = jnp.arange(ctx.shape[1], dtype=jnp.int32)
-        out = chunked_attention(
-            q, k, v, q_pos=positions, kv_pos=kv_pos, causal=False,
-            window=None, chunk=cfg.attn_chunk,
-        )
+        with jax.named_scope("attention"):
+            kv_pos = jnp.arange(ctx.shape[1], dtype=jnp.int32)
+            out = chunked_attention(
+                q, k, v, q_pos=positions, kv_pos=kv_pos, causal=False,
+                window=None, chunk=cfg.attn_chunk,
+            )
     elif cache is not None:
         # Ring-buffer cache {'k','v','pos'} of length cache_len (== window
         # for local attention). The position plane is either shared across
@@ -251,34 +259,37 @@ def attention_apply(
             assert jnp.ndim(idx) == 1 and per_slot, (jnp.ndim(idx), per_slot)
             nb = block_tables.shape[1]
             pos2 = positions  # (B, S): row r writes at idx[r] + [0, S)
-            tok_valid = (jnp.arange(s, dtype=jnp.int32)[None, :]
-                         < q_lens[:, None])  # (B, S)
-            bi = jnp.clip(pos2 // cache_len, 0, nb - 1)
-            phys = jnp.where(tok_valid,
-                             jnp.take_along_axis(block_tables, bi, axis=1),
-                             0)  # (B, S); invalid tokens -> trash block
-            off = jnp.mod(pos2, cache_len)
-            fp, fo = phys.reshape(-1), off.reshape(-1)
-            ck = cache["k"].at[fp, fo].set(
-                kd.reshape((b * s,) + kd.shape[2:]))
-            cv = cache["v"].at[fp, fo].set(
-                vd.reshape((b * s,) + vd.shape[2:]))
-            cp = cache["pos"].at[fp, fo].set(
-                jnp.where(tok_valid, pos2, -1).reshape(-1))
+            with jax.named_scope("kv_commit"):
+                tok_valid = (jnp.arange(s, dtype=jnp.int32)[None, :]
+                             < q_lens[:, None])  # (B, S)
+                bi = jnp.clip(pos2 // cache_len, 0, nb - 1)
+                phys = jnp.where(
+                    tok_valid, jnp.take_along_axis(block_tables, bi, axis=1),
+                    0)  # (B, S); invalid tokens -> trash block
+                off = jnp.mod(pos2, cache_len)
+                fp, fo = phys.reshape(-1), off.reshape(-1)
+                ck = cache["k"].at[fp, fo].set(
+                    kd.reshape((b * s,) + kd.shape[2:]))
+                cv = cache["v"].at[fp, fo].set(
+                    vd.reshape((b * s,) + vd.shape[2:]))
+                cp = cache["pos"].at[fp, fo].set(
+                    jnp.where(tok_valid, pos2, -1).reshape(-1))
             if paged is not None:
                 out = paged_attention_decode(
                     q, ck, cv, cp, block_tables, pos2[:, 0],
                     q_lens=q_lens, causal=causal, window=window, impl=paged)
             else:
-                gk = ck[block_tables].reshape(
-                    (b, nb * cache_len) + ck.shape[2:])
-                gv = cv[block_tables].reshape(
-                    (b, nb * cache_len) + cv.shape[2:])
-                gp = jnp.where((block_tables == 0)[:, :, None], -1,
-                               cp[block_tables]).reshape(b, nb * cache_len)
-                out = chunked_attention(
-                    q, gk, gv, q_pos=pos2, kv_pos=gp, causal=causal,
-                    window=window, chunk=cfg.attn_chunk)
+                with jax.named_scope("attention"):
+                    gk = ck[block_tables].reshape(
+                        (b, nb * cache_len) + ck.shape[2:])
+                    gv = cv[block_tables].reshape(
+                        (b, nb * cache_len) + cv.shape[2:])
+                    gp = jnp.where((block_tables == 0)[:, :, None], -1,
+                                   cp[block_tables]).reshape(
+                                       b, nb * cache_len)
+                    out = chunked_attention(
+                        q, gk, gv, q_pos=pos2, kv_pos=gp, causal=causal,
+                        window=window, chunk=cfg.attn_chunk)
             y = dense(p["wo"], out.reshape(b, s, h * dh), cfg)
             return y, {"k": ck, "v": cv, "pos": cp}
         if jnp.ndim(idx) == 1 and block_tables is not None:
@@ -290,13 +301,14 @@ def attention_apply(
             # place — zero copies, zero recompute.
             assert s == 1 and per_slot, (s, per_slot)
             nb = block_tables.shape[1]
-            bi = idx // cache_len  # logical block of each row's write
-            off = jnp.mod(idx, cache_len)
-            phys = jnp.take_along_axis(block_tables, bi[:, None],
-                                       axis=1)[:, 0]  # (B,)
-            ck = cache["k"].at[phys, off].set(kd[:, 0])
-            cv = cache["v"].at[phys, off].set(vd[:, 0])
-            cp = cache["pos"].at[phys, off].set(new_pos[:, 0])
+            with jax.named_scope("kv_commit"):
+                bi = idx // cache_len  # logical block of each row's write
+                off = jnp.mod(idx, cache_len)
+                phys = jnp.take_along_axis(block_tables, bi[:, None],
+                                           axis=1)[:, 0]  # (B,)
+                ck = cache["k"].at[phys, off].set(kd[:, 0])
+                cv = cache["v"].at[phys, off].set(vd[:, 0])
+                cp = cache["pos"].at[phys, off].set(new_pos[:, 0])
             if paged is not None:
                 # fused path: the kernel indexes the arena through the
                 # table in place — the gathered K/V below never exists
@@ -304,92 +316,100 @@ def attention_apply(
                     q, ck, cv, cp, block_tables, positions[:, 0],
                     causal=causal, window=window, impl=paged)
             else:
-                gk = ck[block_tables].reshape(
-                    (b, nb * cache_len) + ck.shape[2:])
-                gv = cv[block_tables].reshape(
-                    (b, nb * cache_len) + cv.shape[2:])
-                # logical blocks mapped to the trash block (id 0:
-                # unallocated table tails, free slots) are invalid by
-                # definition — their positions must never enter the mask,
-                # whatever garbage the free-slot dummy writes left in
-                # block 0's pos plane
-                gp = jnp.where((block_tables == 0)[:, :, None], -1,
-                               cp[block_tables]).reshape(b, nb * cache_len)
-                out = full_attention(q, gk, gv, q_pos=positions, kv_pos=gp,
-                                     causal=causal, window=window)
+                with jax.named_scope("attention"):
+                    gk = ck[block_tables].reshape(
+                        (b, nb * cache_len) + ck.shape[2:])
+                    gv = cv[block_tables].reshape(
+                        (b, nb * cache_len) + cv.shape[2:])
+                    # logical blocks mapped to the trash block (id 0:
+                    # unallocated table tails, free slots) are invalid by
+                    # definition — their positions must never enter the
+                    # mask, whatever garbage the free-slot dummy writes left
+                    # in block 0's pos plane
+                    gp = jnp.where((block_tables == 0)[:, :, None], -1,
+                                   cp[block_tables]).reshape(
+                                       b, nb * cache_len)
+                    out = full_attention(q, gk, gv, q_pos=positions,
+                                         kv_pos=gp, causal=causal,
+                                         window=window)
             y = dense(p["wo"], out.reshape(b, s, h * dh), cfg)
             return y, {"k": ck, "v": cv, "pos": cp}
-        if jnp.ndim(idx) == 1:
-            # per-slot decode: row r writes token at its own position idx[r]
-            assert s == 1 and per_slot, (s, per_slot)
-            slot = jnp.mod(idx, cache_len)  # (B,)
-            ck = jax.vmap(
-                lambda c, u, sl: jax.lax.dynamic_update_slice(c, u, (sl, 0, 0))
-            )(cache["k"], kd, slot)
-            cv = jax.vmap(
-                lambda c, u, sl: jax.lax.dynamic_update_slice(c, u, (sl, 0, 0))
-            )(cache["v"], vd, slot)
-            cp = jax.vmap(
-                lambda c, u, sl: jax.lax.dynamic_update_slice(c, u, (sl,))
-            )(cache["pos"], new_pos, slot)
-        elif s >= cache_len:
-            # Keep the ring invariant slot == pos % cache_len so later
-            # single-token writes overwrite the *oldest* entry.
-            shift = jnp.mod(new_pos[-cache_len], cache_len)
-            ck = jnp.roll(kd[:, -cache_len:], shift, axis=1)
-            cv = jnp.roll(vd[:, -cache_len:], shift, axis=1)
-            cp = jnp.roll(new_pos[-cache_len:], shift)
-            if per_slot:
-                cp = jnp.broadcast_to(cp[None, :], (b, cache_len))
-        elif s == 1:
-            slot = jnp.mod(idx, cache_len)
-            ck = jax.lax.dynamic_update_slice(cache["k"], kd, (0, slot, 0, 0))
-            cv = jax.lax.dynamic_update_slice(cache["v"], vd, (0, slot, 0, 0))
-            if per_slot:
-                cp = jax.lax.dynamic_update_slice(
-                    cache["pos"], jnp.broadcast_to(new_pos[None, :], (b, 1)),
-                    (0, slot))
-            else:
-                cp = jax.lax.dynamic_update_slice(cache["pos"], new_pos,
-                                                  (slot,))
-        else:  # chunked prefill within capacity (no wrap by construction)
-            ck = jax.lax.dynamic_update_slice(cache["k"], kd, (0, idx, 0, 0))
-            cv = jax.lax.dynamic_update_slice(cache["v"], vd, (0, idx, 0, 0))
-            if per_slot:
-                cp = jax.lax.dynamic_update_slice(
-                    cache["pos"], jnp.broadcast_to(new_pos[None, :], (b, s)),
-                    (0, idx))
-            else:
-                cp = jax.lax.dynamic_update_slice(cache["pos"], new_pos,
-                                                  (idx,))
+        with jax.named_scope("kv_commit"):
+            if jnp.ndim(idx) == 1:
+                # per-slot decode: row r writes its token at position idx[r]
+                assert s == 1 and per_slot, (s, per_slot)
+                slot = jnp.mod(idx, cache_len)  # (B,)
+                ck = jax.vmap(lambda c, u, sl: jax.lax.dynamic_update_slice(
+                    c, u, (sl, 0, 0)))(cache["k"], kd, slot)
+                cv = jax.vmap(lambda c, u, sl: jax.lax.dynamic_update_slice(
+                    c, u, (sl, 0, 0)))(cache["v"], vd, slot)
+                cp = jax.vmap(
+                    lambda c, u, sl: jax.lax.dynamic_update_slice(c, u, (sl,))
+                )(cache["pos"], new_pos, slot)
+            elif s >= cache_len:
+                # Keep the ring invariant slot == pos % cache_len so later
+                # single-token writes overwrite the *oldest* entry.
+                shift = jnp.mod(new_pos[-cache_len], cache_len)
+                ck = jnp.roll(kd[:, -cache_len:], shift, axis=1)
+                cv = jnp.roll(vd[:, -cache_len:], shift, axis=1)
+                cp = jnp.roll(new_pos[-cache_len:], shift)
+                if per_slot:
+                    cp = jnp.broadcast_to(cp[None, :], (b, cache_len))
+            elif s == 1:
+                slot = jnp.mod(idx, cache_len)
+                ck = jax.lax.dynamic_update_slice(cache["k"], kd,
+                                                  (0, slot, 0, 0))
+                cv = jax.lax.dynamic_update_slice(cache["v"], vd,
+                                                  (0, slot, 0, 0))
+                if per_slot:
+                    cp = jax.lax.dynamic_update_slice(
+                        cache["pos"],
+                        jnp.broadcast_to(new_pos[None, :], (b, 1)), (0, slot))
+                else:
+                    cp = jax.lax.dynamic_update_slice(cache["pos"], new_pos,
+                                                      (slot,))
+            else:  # chunked prefill within capacity (no wrap by design)
+                ck = jax.lax.dynamic_update_slice(cache["k"], kd,
+                                                  (0, idx, 0, 0))
+                cv = jax.lax.dynamic_update_slice(cache["v"], vd,
+                                                  (0, idx, 0, 0))
+                if per_slot:
+                    cp = jax.lax.dynamic_update_slice(
+                        cache["pos"],
+                        jnp.broadcast_to(new_pos[None, :], (b, s)), (0, idx))
+                else:
+                    cp = jax.lax.dynamic_update_slice(cache["pos"], new_pos,
+                                                      (idx,))
         new_cache = {"k": ck, "v": cv, "pos": cp}
-        if s == 1:
-            # decode: attend over the (ring) cache
-            out = full_attention(q, ck, cv, q_pos=positions, kv_pos=cp,
-                                 causal=causal, window=window)
-        elif attend_cache and s < cache_len:
-            # chunk / suffix prefill past a committed position: the cache
-            # rows [0, cache_index) hold valid K/V (cached prefix and/or
-            # earlier chunks) and this chunk was just written at
-            # [cache_index, cache_index + s), so the chunk's queries
-            # attend over the whole updated cache (invalid entries are
-            # pos == -1 and masked as always).
-            out = chunked_attention(
-                q, ck, cv, q_pos=positions, kv_pos=cp, causal=causal,
-                window=window, chunk=cfg.attn_chunk)
-        else:
-            # whole-prompt prefill: the ring cache only retains the last
-            # `cache_len` KVs, so early queries must attend over the full
-            # current K/V (cache is write-only here; decode reads it).
+        with jax.named_scope("attention"):
+            if s == 1:
+                # decode: attend over the (ring) cache
+                out = full_attention(q, ck, cv, q_pos=positions, kv_pos=cp,
+                                     causal=causal, window=window)
+            elif attend_cache and s < cache_len:
+                # chunk / suffix prefill past a committed position: the
+                # cache rows [0, cache_index) hold valid K/V (cached prefix
+                # and/or earlier chunks) and this chunk was just written at
+                # [cache_index, cache_index + s), so the chunk's queries
+                # attend over the whole updated cache (invalid entries are
+                # pos == -1 and masked as always).
+                out = chunked_attention(
+                    q, ck, cv, q_pos=positions, kv_pos=cp, causal=causal,
+                    window=window, chunk=cfg.attn_chunk)
+            else:
+                # whole-prompt prefill: the ring cache only retains the
+                # last `cache_len` KVs, so early queries must attend over
+                # the full current K/V (cache is write-only here; decode
+                # reads it).
+                out = chunked_attention(
+                    q, k, v, q_pos=positions, kv_pos=positions,
+                    causal=causal, window=window, chunk=cfg.attn_chunk)
+    else:
+        with jax.named_scope("attention"):
             out = chunked_attention(
                 q, k, v, q_pos=positions, kv_pos=positions, causal=causal,
-                window=window, chunk=cfg.attn_chunk)
-    else:
-        kv_pos = positions
-        out = chunked_attention(
-            q, k, v, q_pos=positions, kv_pos=kv_pos, causal=causal,
-            window=window, chunk=cfg.attn_chunk,
-        )
+                window=window, chunk=cfg.attn_chunk,
+            )
 
     y = dense(p["wo"], out.reshape(b, s, h * dh), cfg)
     return y, new_cache

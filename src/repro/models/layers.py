@@ -53,16 +53,17 @@ def build_embed(cfg: ArchConfig) -> dict:
 
 
 def norm_apply(p: dict, x: jnp.ndarray, cfg: ArchConfig, eps: float = 1e-6):
-    dt = x.dtype
-    x32 = x.astype(jnp.float32)
-    if cfg.norm == "rms":
-        var = jnp.mean(x32 * x32, axis=-1, keepdims=True)
-        y = x32 * jax.lax.rsqrt(var + eps)
-    else:  # LayerNorm without bias
-        mu = jnp.mean(x32, axis=-1, keepdims=True)
-        var = jnp.var(x32, axis=-1, keepdims=True)
-        y = (x32 - mu) * jax.lax.rsqrt(var + eps)
-    return (y * p["scale"].astype(jnp.float32)).astype(dt)
+    with jax.named_scope("norm"):
+        dt = x.dtype
+        x32 = x.astype(jnp.float32)
+        if cfg.norm == "rms":
+            var = jnp.mean(x32 * x32, axis=-1, keepdims=True)
+            y = x32 * jax.lax.rsqrt(var + eps)
+        else:  # LayerNorm without bias
+            mu = jnp.mean(x32, axis=-1, keepdims=True)
+            var = jnp.var(x32, axis=-1, keepdims=True)
+            y = (x32 - mu) * jax.lax.rsqrt(var + eps)
+        return (y * p["scale"].astype(jnp.float32)).astype(dt)
 
 
 def dense(p: dict, x: jnp.ndarray, cfg: ArchConfig) -> jnp.ndarray:
@@ -72,7 +73,14 @@ def dense(p: dict, x: jnp.ndarray, cfg: ArchConfig) -> jnp.ndarray:
     repro.serve.quantized), the matmul consumes the compressed bit-planes —
     the Pallas kernel dequantizes in VMEM on TPU; the jnp reference path
     does the same math on CPU/dry-run with identical packed HBM operands.
+    Every op of it, bf16 or packed, carries the ``gemm`` name scope in the
+    step programs' metadata (docs/serving.md "Observability").
     """
+    with jax.named_scope("gemm"):
+        return _dense(p, x, cfg)
+
+
+def _dense(p: dict, x: jnp.ndarray, cfg: ArchConfig) -> jnp.ndarray:
     w = p["w"]
     if isinstance(w, dict) and "mask_planes" in w:
         from repro.kernels import ops
@@ -109,20 +117,22 @@ def mlp_apply(p: dict, x: jnp.ndarray, cfg: ArchConfig) -> jnp.ndarray:
 
 
 def embed_apply(p: dict, tokens: jnp.ndarray, cfg: ArchConfig) -> jnp.ndarray:
-    e = p["tok"]
-    if cfg.quant.quantize_embeddings:
-        e = maybe_quant(e, cfg.quant.cfg, cfg.quant.mode)
-    dt = jnp.dtype(cfg.compute_dtype)
-    return jnp.take(e, tokens, axis=0).astype(dt)
+    with jax.named_scope("embed"):
+        e = p["tok"]
+        if cfg.quant.quantize_embeddings:
+            e = maybe_quant(e, cfg.quant.cfg, cfg.quant.mode)
+        dt = jnp.dtype(cfg.compute_dtype)
+        return jnp.take(e, tokens, axis=0).astype(dt)
 
 
 def unembed_apply(p: dict, x: jnp.ndarray, cfg: ArchConfig) -> jnp.ndarray:
-    if cfg.tie_embeddings:
-        w = p["tok"].T
-    else:
-        w = p["unembed"]
-    # logits in fp32 for a stable softmax-CE
-    return (x @ w.astype(x.dtype)).astype(jnp.float32)
+    with jax.named_scope("gemm"):
+        if cfg.tie_embeddings:
+            w = p["tok"].T
+        else:
+            w = p["unembed"]
+        # logits in fp32 for a stable softmax-CE
+        return (x @ w.astype(x.dtype)).astype(jnp.float32)
 
 
 # ---------------------------------------------------------------------------

@@ -50,6 +50,31 @@ _COST_EDGES = cost_buckets()
 _COST_FIELDS = ("flops", "hbm_bytes", "swis_cycles")
 
 
+class _Phase:
+    """One engine phase: a ``jax.profiler`` span ``serve.<name>`` around
+    the phase's timer. The span lands in the profiler's host plane, on the
+    device trace's clock, only while a profiler trace records; otherwise
+    entering it costs about a microsecond. ``with`` yields the span, so a
+    dispatch phase can attach its launch's counts
+    (``if span.is_enabled(): span.set_metadata(...)``)."""
+
+    __slots__ = ("span", "_timer")
+
+    def __init__(self, name: str, timer):
+        self.span = jax.profiler.TraceAnnotation(name)
+        self._timer = timer
+
+    def __enter__(self):
+        self.span.__enter__()
+        self._timer.__enter__()
+        return self.span
+
+    def __exit__(self, *exc):
+        self._timer.__exit__(*exc)
+        self.span.__exit__(*exc)
+        return False
+
+
 @jax.jit
 def sample_step(logits, keys, steps, temps):
     """Seeded per-row sampling shared by both engines.
@@ -379,20 +404,22 @@ class ContinuousBatchingEngine:
         (``step.mixed_dispatch_s``) instead of two sequenced launches.
 
         Phase timers (``step.*_s`` histograms in ``metrics_registry``):
-        admit, prefix_match, prefill_dispatch, chunk_advance,
-        mixed_dispatch, decode_dispatch, device_sync, sample_host — plus
-        ``step.total_s`` for the whole round. ``step.model_dispatches``
-        counts forward launches (the fused win the dispatch-count test and
-        the mixed_load bench gate measure)."""
+        admit, prefix_match, group_setup, build_batch, prefill_dispatch,
+        chunk_advance, mixed_dispatch, decode_dispatch, device_sync,
+        sample_host, record — plus ``step.total_s`` for the whole round.
+        Each phase is also a profiler span ``serve.<phase>`` (docs/
+        serving.md "Observability"). ``step.model_dispatches`` counts
+        forward launches (the fused win the dispatch-count test and the
+        mixed_load bench gate measure)."""
         m = self.metrics_registry
         self.tracer.current_step = self._step_no
         with self._phase("step.total_s", "step"):
             if len(self._prefill_groups) < self.prefill_backlog:
                 with self._phase("step.admit_s", "admit"):
                     admitted = self.scheduler.admit()
-                if admitted:
                     for slot, st in admitted:
                         self.tracer.event(tr.ADMIT, st.req.rid, slot=slot)
+                if admitted:
                     self._prefill_admitted(admitted)
             decoded = False
             if self._prefill_groups:
@@ -409,11 +436,13 @@ class ContinuousBatchingEngine:
                     self._spec_once()
                 else:
                     self._decode_once()
-            finished = self.scheduler.pop_finished()
-        for f in finished:
-            self.tracer.event(tr.FINISH, f.rid, n_tokens=len(f.tokens))
-        m.counter("step.count").inc()
-        self._step_no += 1
+            with self._phase("step.record_s", "record"):
+                finished = self.scheduler.pop_finished()
+                for f in finished:
+                    self.tracer.event(tr.FINISH, f.rid,
+                                      n_tokens=len(f.tokens))
+                m.counter("step.count").inc()
+                self._step_no += 1
         if m.enabled:
             # model-vs-measured utilization: bytes the cost model says the
             # issued dispatches should have moved, over measured step time
@@ -491,15 +520,18 @@ class ContinuousBatchingEngine:
 
     # -- observability ---------------------------------------------------
 
-    def _phase(self, hist: str, span: str):
+    def _phase(self, hist: str, span: str) -> _Phase:
         """Phase timing context: one clock-pair feeds the ``hist``
         histogram AND (tracer enabled) a named span in the trace ring —
         the span nests under the enclosing ``step`` span by timestamp
-        containment in the Chrome trace export."""
+        containment in the Chrome trace export. The same phase is a
+        profiler span ``serve.<span>`` whether or not metrics are on."""
         if self.tracer.enabled:
-            return self.tracer.span_timer(
+            timer = self.tracer.span_timer(
                 span, self.metrics_registry.histogram(hist))
-        return self.metrics_registry.timer(hist)
+        else:
+            timer = self.metrics_registry.timer(hist)
+        return _Phase("serve." + span, timer)
 
     def _record_cost(self, cost) -> None:
         """Record one dispatch's predicted cost: global + per-kind
@@ -671,46 +703,72 @@ class ContinuousBatchingEngine:
             with self._phase("step.prefix_match_s", "prefix_match"):
                 admitted = self._assign_blocks(admitted)
             if self.prefill_chunk is not None:
-                with self._phase("step.chunk_advance_s", "chunk_advance"):
+                # staging stays in ``chunk_advance``, its histogram's
+                # meaning on both paths; ``group_setup`` is the inner span
+                with self._phase("step.chunk_advance_s", "chunk_advance"), \
+                        self._phase("step.group_setup_s", "group_setup"):
                     self._stage_chunked(admitted)
                 return
-        with self._phase("step.prefill_dispatch_s", "prefill_dispatch"):
-            self._run_prefill(admitted)
+        self._run_prefill(admitted)
+
+    def _dispatch_counts(self, span, start, q_lens, n_decode: int) -> None:
+        """Attach a launch's counts to its dispatch span, only while a
+        profiler trace records. Rows ``[0, n_decode)`` are decode rows, the
+        rest a chunk group's; row r feeds ``q_lens[r]`` tokens from
+        position ``start[r]`` and so attends ``start[r] + q_lens[r]``
+        positions."""
+        if not span.is_enabled():
+            return
+        live = q_lens > 0
+        span.set_metadata(
+            decode_rows=int(live[:n_decode].sum()),
+            chunk_rows=int(live[n_decode:].sum()),
+            chunk_tokens=int(q_lens[n_decode:].sum()),
+            kv_tokens=int((start + q_lens)[live].sum()))
 
     def _run_prefill(self, admitted) -> None:
-        groups: Dict[Any, list] = {}
-        for slot, st in admitted:
-            ex = st.req.extra
-            sig = (tuple(sorted((k, np.shape(v)) for k, v in ex.items()))
-                   if ex else None)
-            pb = (self._slot_meta[slot]["prefix_blocks"]
-                  if self.prefix_cache is not None else 0)
-            p_len = pb * (self.cache.block_size or 0)
-            s_real = len(st.req.prompt) - p_len
-            groups.setdefault((p_len, self._bucket(s_real, p_len), sig),
-                              []).append((slot, st))
+        with self._phase("step.group_setup_s", "group_setup"):
+            groups: Dict[Any, list] = {}
+            for slot, st in admitted:
+                ex = st.req.extra
+                sig = (tuple(sorted((k, np.shape(v))
+                                    for k, v in ex.items()))
+                       if ex else None)
+                pb = (self._slot_meta[slot]["prefix_blocks"]
+                      if self.prefix_cache is not None else 0)
+                p_len = pb * (self.cache.block_size or 0)
+                s_real = len(st.req.prompt) - p_len
+                groups.setdefault((p_len, self._bucket(s_real, p_len), sig),
+                                  []).append((slot, st))
         for (p_len, s_pad, _), group in groups.items():
-            g = len(group)
-            toks = np.zeros((g, s_pad), np.int32)
-            lasts = np.empty(g, np.int32)
-            for i, (_, st) in enumerate(group):
-                sfx = st.req.prompt[p_len:]
-                toks[i, :len(sfx)] = sfx
-                lasts[i] = len(sfx) - 1
-            batch = {"tokens": jnp.asarray(toks)}
-            extras = [st.req.extra for _, st in group]
-            if extras[0]:
-                for k in extras[0]:
-                    batch[k] = jnp.asarray(
-                        np.stack([ex[k] for ex in extras]))
-            last_idx = jnp.asarray(lasts)
-            self._stat_prefill_tokens += int(lasts.sum()) + g
-            self.metrics_registry.counter("step.model_dispatches").inc()
-            self._record_cost(self.cost_model.prefill(g, s_pad))
-            if self.prefix_cache is not None:
-                meta = [self._slot_meta[slot] for slot, _ in group]
-                cache = self.cache.prefix_tree(
-                    [m["matched"] for m in meta], p_len)
+            with self._phase("step.build_batch_s", "build_batch"):
+                g = len(group)
+                toks = np.zeros((g, s_pad), np.int32)
+                lasts = np.empty(g, np.int32)
+                for i, (_, st) in enumerate(group):
+                    sfx = st.req.prompt[p_len:]
+                    toks[i, :len(sfx)] = sfx
+                    lasts[i] = len(sfx) - 1
+                batch = {"tokens": jnp.asarray(toks)}
+                extras = [st.req.extra for _, st in group]
+                if extras[0]:
+                    for k in extras[0]:
+                        batch[k] = jnp.asarray(
+                            np.stack([ex[k] for ex in extras]))
+                last_idx = jnp.asarray(lasts)
+                self._stat_prefill_tokens += int(lasts.sum()) + g
+                self.metrics_registry.counter("step.model_dispatches").inc()
+                self._record_cost(self.cost_model.prefill(g, s_pad))
+                if self.prefix_cache is not None:
+                    meta = [self._slot_meta[slot] for slot, _ in group]
+                    cache = self.cache.prefix_tree(
+                        [m["matched"] for m in meta], p_len)
+                else:
+                    cache = self.cache.fresh(g)
+            with self._phase("step.prefill_dispatch_s",
+                             "prefill_dispatch") as span:
+                self._dispatch_counts(
+                    span, np.full(g, p_len, np.int32), lasts + 1, 0)
                 if p_len:
                     logits, cache = self._prefill_sfx(
                         self.params, batch, cache, jnp.int32(p_len),
@@ -718,26 +776,28 @@ class ContinuousBatchingEngine:
                 else:
                     logits, cache = self._prefill_flat(
                         self.params, batch, cache, last_idx)
-                for i, (slot, st) in enumerate(group):
-                    self.cache.scatter_row(
-                        cache, i, meta[i]["owned"],
-                        meta[i]["prefix_blocks"],
-                        len(st.req.prompt) - p_len)
-            else:
-                cache = self.cache.fresh(g)
-                logits, cache = self._prefill_flat(
-                    self.params, batch, cache, last_idx)
-                cache = self.cache.mask_pos_tail(
-                    cache, [len(st.req.prompt) for _, st in group])
-                self.cache.write_slots(cache, [slot for slot, _ in group])
-            keys = jnp.stack([st.req.key for _, st in group])
-            temps = jnp.asarray(
-                [st.req.temperature for _, st in group], jnp.float32)
-            steps = jnp.zeros(g, jnp.int32)
-            first = np.asarray(sample_step(logits, keys, steps, temps))
-            for (slot, st), tok in zip(group, first):
-                self.tracer.event(tr.FIRST_TOKEN, st.req.rid, slot=slot)
-                self.scheduler.record_prefill(slot, tok)
+            with self._phase("step.record_s", "record"):
+                if self.prefix_cache is not None:
+                    for i, (slot, st) in enumerate(group):
+                        self.cache.scatter_row(
+                            cache, i, meta[i]["owned"],
+                            meta[i]["prefix_blocks"],
+                            len(st.req.prompt) - p_len)
+                else:
+                    cache = self.cache.mask_pos_tail(
+                        cache, [len(st.req.prompt) for _, st in group])
+                    self.cache.write_slots(cache,
+                                           [slot for slot, _ in group])
+            with self._phase("step.sample_host_s", "sample_host"):
+                keys = jnp.stack([st.req.key for _, st in group])
+                temps = jnp.asarray(
+                    [st.req.temperature for _, st in group], jnp.float32)
+                steps = jnp.zeros(g, jnp.int32)
+                first = np.asarray(sample_step(logits, keys, steps, temps))
+            with self._phase("step.record_s", "record"):
+                for (slot, st), tok in zip(group, first):
+                    self.tracer.event(tr.FIRST_TOKEN, st.req.rid, slot=slot)
+                    self.scheduler.record_prefill(slot, tok)
 
     def _stage_chunked(self, admitted) -> None:
         """Stage admitted requests as chunk-prefill groups (no model work
@@ -825,60 +885,74 @@ class ContinuousBatchingEngine:
         s_chunk = grp["tail"] if final else chunk
         lo = k * chunk
         g = len(grp["members"])
-        batch = {"tokens": jnp.asarray(grp["toks"][:, lo:lo + s_chunk])}
-        extras = grp["extra"]
-        if extras[0]:
-            for key in extras[0]:
-                batch[key] = jnp.asarray(
-                    np.stack([ex[key] for ex in extras]))
-        last_idx = (jnp.asarray(grp["lasts"]) if final
-                    else jnp.full((g,), s_chunk - 1, jnp.int32))
         committed = grp["p_len"] + lo
-        self._stat_chunk_steps += 1
-        self.metrics_registry.counter("step.model_dispatches").inc()
-        self._record_cost(self.cost_model.chunk(g, s_chunk,
-                                                grp["tree_len"]))
-        if committed == 0:
-            # first chunk of an uncached prompt: nothing committed, the
-            # chunk attends over its own K/V like a whole-prompt prefill
-            logits, tree = self._prefill_flat(self.params, batch,
-                                              grp["tree"], last_idx)
-        else:
-            logits, tree = self._prefill_sfx(self.params, batch,
-                                             grp["tree"],
-                                             jnp.int32(committed), last_idx)
-        grp["tree"] = tree
-        grp["done"] = k + 1
-        # append this chunk at its offset into each row's owned blocks
-        b0 = lo // bs
-        for i, (slot, st) in enumerate(grp["members"]):
-            meta = grp["metas"][i]
-            n_valid = min(len(st.req.prompt) - grp["p_len"] - lo, s_chunk)
-            nb = -(-n_valid // bs)
-            self.cache.scatter_row(tree, i, meta["owned"][b0:b0 + nb],
-                                   meta["prefix_blocks"] + b0, n_valid)
-            self.tracer.event(tr.PREFILL_CHUNK, st.req.rid, slot=slot,
-                              index=k, n_chunks=grp["n_chunks"],
-                              tokens=int(n_valid))
-        if not final:
-            # round-robin across in-flight groups: a 1-chunk group (short
-            # prompt) admitted behind a long prefill is serviced on the
-            # very next step instead of waiting out every long chunk
-            self._prefill_groups.rotate(-1)
-            return
-        self._prefill_groups.popleft()
-        for i, (slot, st) in enumerate(grp["members"]):
-            meta = grp["metas"][i]
-            self.cache.set_table(slot, meta["matched"] + meta["owned"])
-            self._stat_prefill_tokens += len(st.req.prompt) - grp["p_len"]
-        keys = jnp.stack([st.req.key for _, st in grp["members"]])
-        temps = jnp.asarray(
-            [st.req.temperature for _, st in grp["members"]], jnp.float32)
-        first = np.asarray(sample_step(logits, keys,
-                                       jnp.zeros(g, jnp.int32), temps))
-        for (slot, st), tok in zip(grp["members"], first):
-            self.tracer.event(tr.FIRST_TOKEN, st.req.rid, slot=slot)
-            self.scheduler.record_prefill(slot, tok)
+        with self._phase("step.build_batch_s", "build_batch"):
+            batch = {"tokens": jnp.asarray(grp["toks"][:, lo:lo + s_chunk])}
+            extras = grp["extra"]
+            if extras[0]:
+                for key in extras[0]:
+                    batch[key] = jnp.asarray(
+                        np.stack([ex[key] for ex in extras]))
+            last_idx = (jnp.asarray(grp["lasts"]) if final
+                        else jnp.full((g,), s_chunk - 1, jnp.int32))
+            n_valids = np.asarray(
+                [min(len(st.req.prompt) - grp["p_len"] - lo, s_chunk)
+                 for _, st in grp["members"]], np.int32)
+            self._stat_chunk_steps += 1
+            self.metrics_registry.counter("step.model_dispatches").inc()
+            self._record_cost(self.cost_model.chunk(g, s_chunk,
+                                                    grp["tree_len"]))
+        with self._phase("step.prefill_dispatch_s",
+                         "prefill_dispatch") as span:
+            self._dispatch_counts(span, np.full(g, committed, np.int32),
+                                  n_valids, 0)
+            if committed == 0:
+                # first chunk of an uncached prompt: nothing committed, the
+                # chunk attends over its own K/V like a whole-prompt prefill
+                logits, tree = self._prefill_flat(self.params, batch,
+                                                  grp["tree"], last_idx)
+            else:
+                logits, tree = self._prefill_sfx(
+                    self.params, batch, grp["tree"], jnp.int32(committed),
+                    last_idx)
+        with self._phase("step.record_s", "record"):
+            grp["tree"] = tree
+            grp["done"] = k + 1
+            # append this chunk at its offset into each row's owned blocks
+            b0 = lo // bs
+            for i, (slot, st) in enumerate(grp["members"]):
+                meta = grp["metas"][i]
+                n_valid = int(n_valids[i])
+                nb = -(-n_valid // bs)
+                self.cache.scatter_row(tree, i, meta["owned"][b0:b0 + nb],
+                                       meta["prefix_blocks"] + b0, n_valid)
+                self.tracer.event(tr.PREFILL_CHUNK, st.req.rid, slot=slot,
+                                  index=k, n_chunks=grp["n_chunks"],
+                                  tokens=n_valid)
+            if not final:
+                # round-robin across in-flight groups: a 1-chunk group
+                # (short prompt) admitted behind a long prefill is serviced
+                # on the very next step instead of waiting out every long
+                # chunk
+                self._prefill_groups.rotate(-1)
+                return
+            self._prefill_groups.popleft()
+            for i, (slot, st) in enumerate(grp["members"]):
+                meta = grp["metas"][i]
+                self.cache.set_table(slot, meta["matched"] + meta["owned"])
+                self._stat_prefill_tokens += (len(st.req.prompt)
+                                              - grp["p_len"])
+        with self._phase("step.sample_host_s", "sample_host"):
+            keys = jnp.stack([st.req.key for _, st in grp["members"]])
+            temps = jnp.asarray(
+                [st.req.temperature for _, st in grp["members"]],
+                jnp.float32)
+            first = np.asarray(sample_step(logits, keys,
+                                           jnp.zeros(g, jnp.int32), temps))
+        with self._phase("step.record_s", "record"):
+            for (slot, st), tok in zip(grp["members"], first):
+                self.tracer.event(tr.FIRST_TOKEN, st.req.rid, slot=slot)
+                self.scheduler.record_prefill(slot, tok)
 
     def _mixed_once(self) -> None:
         """Service the head fused chunk group AND the whole decode batch
@@ -902,88 +976,95 @@ class ContinuousBatchingEngine:
         lo = k * chunk
         g = len(grp["members"])
         n = self.n_slots
-        toks, idxs, steps, temps, keys = self.scheduler.decode_batch(
-            self._dummy_key)
-        decoding = self.scheduler.decoding_slots()
-        live = [(s, self.scheduler.slots[s].req.rid, int(steps[s]))
-                for s in decoding] if self.tracer.enabled else []
-        btoks = np.zeros((n + g, s_chunk), np.int32)
-        btoks[:n, 0] = toks
-        btoks[n:] = grp["toks"][:, lo:lo + s_chunk]
-        q_lens = np.zeros(n + g, np.int32)
-        q_lens[decoding] = 1
-        start = np.zeros(n + g, np.int32)
-        start[:n] = idxs
-        start[n:] = grp["p_len"] + lo
-        last_idx = np.zeros(n + g, np.int32)
-        last_idx[n:] = grp["lasts"] if final else s_chunk - 1
-        n_valids = []
-        for i, (slot, st) in enumerate(grp["members"]):
-            nv = min(len(st.req.prompt) - grp["p_len"] - lo, s_chunk)
-            n_valids.append(nv)
-            q_lens[n + i] = nv
-        tables = np.concatenate([self.cache.block_tables, grp["tables"]])
-        self._stat_chunk_steps += 1
-        m.counter("step.model_dispatches").inc()
-        self._record_cost(self.cost_model.mixed(n + g, s_chunk))
-        with self._phase("step.mixed_dispatch_s", "mixed_dispatch"):
-            logits, tree = self._mixed(
-                self.params, {"tokens": jnp.asarray(btoks)},
-                self.cache.tree, jnp.asarray(start), jnp.asarray(q_lens),
-                jnp.asarray(last_idx), jnp.asarray(tables))
+        with self._phase("step.build_batch_s", "build_batch"):
+            toks, idxs, steps, temps, keys = self.scheduler.decode_batch(
+                self._dummy_key)
+            decoding = self.scheduler.decoding_slots()
+            live = [(s, self.scheduler.slots[s].req.rid, int(steps[s]))
+                    for s in decoding] if self.tracer.enabled else []
+            btoks = np.zeros((n + g, s_chunk), np.int32)
+            btoks[:n, 0] = toks
+            btoks[n:] = grp["toks"][:, lo:lo + s_chunk]
+            q_lens = np.zeros(n + g, np.int32)
+            q_lens[decoding] = 1
+            start = np.zeros(n + g, np.int32)
+            start[:n] = idxs
+            start[n:] = grp["p_len"] + lo
+            last_idx = np.zeros(n + g, np.int32)
+            last_idx[n:] = grp["lasts"] if final else s_chunk - 1
+            for i, (slot, st) in enumerate(grp["members"]):
+                q_lens[n + i] = min(len(st.req.prompt) - grp["p_len"] - lo,
+                                    s_chunk)
+            tables = np.concatenate([self.cache.block_tables,
+                                     grp["tables"]])
+            self._stat_chunk_steps += 1
+            m.counter("step.model_dispatches").inc()
+            self._record_cost(self.cost_model.mixed(n + g, s_chunk))
+            args = (jnp.asarray(start), jnp.asarray(q_lens),
+                    jnp.asarray(last_idx), jnp.asarray(tables))
+            batch = {"tokens": jnp.asarray(btoks)}
+        with self._phase("step.mixed_dispatch_s", "mixed_dispatch") as span:
+            self._dispatch_counts(span, start, q_lens, n)
+            logits, tree = self._mixed(self.params, batch, self.cache.tree,
+                                       *args)
             self.cache.tree = tree
         if m.enabled:
             with self._phase("step.device_sync_s", "device_sync"):
                 jax.block_until_ready(logits)
-        all_keys = list(keys) + [st.req.key for _, st in grp["members"]]
-        all_steps = np.concatenate([steps, np.zeros(g, np.int32)])
-        all_temps = np.concatenate(
-            [temps, np.asarray([st.req.temperature
-                                for _, st in grp["members"]], np.float32)])
         with self._phase("step.sample_host_s", "sample_host"):
+            all_keys = list(keys) + [st.req.key for _, st in grp["members"]]
+            all_steps = np.concatenate([steps, np.zeros(g, np.int32)])
+            all_temps = np.concatenate(
+                [temps, np.asarray([st.req.temperature
+                                    for _, st in grp["members"]],
+                                   np.float32)])
             nxt = np.asarray(sample_step(
                 logits, jnp.stack(all_keys), jnp.asarray(all_steps),
                 jnp.asarray(all_temps)))
+        with self._phase("step.record_s", "record"):
             self.scheduler.record_decode(nxt[:n])
-        for slot, rid, step in live:
-            self.tracer.event(tr.DECODE_STEP, rid, slot=slot, step=step)
-        grp["done"] = k + 1
-        for i, (slot, st) in enumerate(grp["members"]):
-            self.tracer.event(tr.PREFILL_CHUNK, st.req.rid, slot=slot,
-                              index=k, n_chunks=grp["n_chunks"],
-                              tokens=int(n_valids[i]))
-        if not final:
-            self._prefill_groups.rotate(-1)
-            return
-        self._prefill_groups.popleft()
-        for i, (slot, st) in enumerate(grp["members"]):
-            meta = grp["metas"][i]
-            self.cache.set_table(slot, meta["matched"] + meta["owned"])
-            self._stat_prefill_tokens += len(st.req.prompt) - grp["p_len"]
-            self.tracer.event(tr.FIRST_TOKEN, st.req.rid, slot=slot)
-            self.scheduler.record_prefill(slot, int(nxt[n + i]))
+            for slot, rid, step in live:
+                self.tracer.event(tr.DECODE_STEP, rid, slot=slot, step=step)
+            grp["done"] = k + 1
+            for i, (slot, st) in enumerate(grp["members"]):
+                self.tracer.event(tr.PREFILL_CHUNK, st.req.rid, slot=slot,
+                                  index=k, n_chunks=grp["n_chunks"],
+                                  tokens=int(q_lens[n + i]))
+            if not final:
+                self._prefill_groups.rotate(-1)
+                return
+            self._prefill_groups.popleft()
+            for i, (slot, st) in enumerate(grp["members"]):
+                meta = grp["metas"][i]
+                self.cache.set_table(slot, meta["matched"] + meta["owned"])
+                self._stat_prefill_tokens += (len(st.req.prompt)
+                                              - grp["p_len"])
+                self.tracer.event(tr.FIRST_TOKEN, st.req.rid, slot=slot)
+                self.scheduler.record_prefill(slot, int(nxt[n + i]))
 
     def _decode_once(self) -> None:
         m = self.metrics_registry
-        toks, idxs, steps, temps, keys = self.scheduler.decode_batch(
-            self._dummy_key)
-        # (slot, rid, step) of the live rows — captured before
-        # record_decode frees finished slots
-        live = [(s, self.scheduler.slots[s].req.rid, int(steps[s]))
-                for s in self.scheduler.decoding_slots()] \
-            if self.tracer.enabled else []
-        m.counter("step.model_dispatches").inc()
-        self._record_cost(self.cost_model.decode(self.n_slots))
-        with self._phase("step.decode_dispatch_s", "decode_dispatch"):
+        with self._phase("step.build_batch_s", "build_batch"):
+            toks, idxs, steps, temps, keys = self.scheduler.decode_batch(
+                self._dummy_key)
+            decoding = self.scheduler.decoding_slots()
+            # (slot, rid, step) of the live rows — captured before
+            # record_decode frees finished slots
+            live = [(s, self.scheduler.slots[s].req.rid, int(steps[s]))
+                    for s in decoding] if self.tracer.enabled else []
+            m.counter("step.model_dispatches").inc()
+            self._record_cost(self.cost_model.decode(self.n_slots))
+            args = (jnp.asarray(toks)[:, None], self.cache.tree,
+                    jnp.asarray(idxs))
             if self.prefix_cache is not None:
-                logits, tree = self._decode(
-                    self.params, jnp.asarray(toks)[:, None],
-                    self.cache.tree, jnp.asarray(idxs),
-                    self.cache.tables_device())
-            else:
-                logits, tree = self._decode(
-                    self.params, jnp.asarray(toks)[:, None],
-                    self.cache.tree, jnp.asarray(idxs))
+                args += (self.cache.tables_device(),)
+        with self._phase("step.decode_dispatch_s",
+                         "decode_dispatch") as span:
+            if span.is_enabled():
+                q1 = np.zeros(self.n_slots, np.int32)
+                q1[decoding] = 1
+                self._dispatch_counts(span, idxs, q1, self.n_slots)
+            logits, tree = self._decode(self.params, *args)
             self.cache.tree = tree
         if m.enabled:
             # split device wait from host-side sampling: logits are about
@@ -991,11 +1072,13 @@ class ContinuousBatchingEngine:
             with self._phase("step.device_sync_s", "device_sync"):
                 jax.block_until_ready(logits)
         with self._phase("step.sample_host_s", "sample_host"):
-            nxt = sample_step(logits, jnp.stack(keys), jnp.asarray(steps),
-                              jnp.asarray(temps))
-            self.scheduler.record_decode(np.asarray(nxt))
-        for slot, rid, step in live:
-            self.tracer.event(tr.DECODE_STEP, rid, slot=slot, step=step)
+            nxt = np.asarray(sample_step(
+                logits, jnp.stack(keys), jnp.asarray(steps),
+                jnp.asarray(temps)))
+        with self._phase("step.record_s", "record"):
+            self.scheduler.record_decode(nxt)
+            for slot, rid, step in live:
+                self.tracer.event(tr.DECODE_STEP, rid, slot=slot, step=step)
 
     def _spec_once(self) -> None:
         """One self-speculative decode round over the DECODING slots.
@@ -1020,58 +1103,68 @@ class ContinuousBatchingEngine:
         accept pattern.
         """
         m = self.metrics_registry
-        toks, idxs, steps, temps, keys = self.scheduler.decode_batch(
-            self._dummy_key)
-        decoding = self.scheduler.decoding_slots()
         n = self.n_slots
-        k_rows = np.zeros(n, np.int32)
-        for s in decoding:
-            st = self.scheduler.slots[s]
-            k_rows[s] = min(self.spec_k, st.req.n_tokens - st.n_gen - 1)
-        k_max = int(k_rows.max(initial=0))
+        with self._phase("step.build_batch_s", "build_batch"):
+            toks, idxs, steps, temps, keys = self.scheduler.decode_batch(
+                self._dummy_key)
+            decoding = self.scheduler.decoding_slots()
+            k_rows = np.zeros(n, np.int32)
+            for s in decoding:
+                st = self.scheduler.slots[s]
+                k_rows[s] = min(self.spec_k, st.req.n_tokens - st.n_gen - 1)
+            k_max = int(k_rows.max(initial=0))
+            if k_max:
+                live = [(s, self.scheduler.slots[s].req.rid, int(steps[s]))
+                        for s in decoding] if self.tracer.enabled else []
+                tables = self.cache.tables_device()
+                keys_dev = jnp.stack(keys)
+                zeros = jnp.zeros(n, jnp.int32)
+                draft_toks = np.zeros((n, k_max), np.int32)
+                cur = toks
+                m.counter("spec.steps").inc()
         if k_max == 0:
             # every live row is one token from its budget: speculation
             # degenerates to plain decode, so run exactly that
             self._decode_once()
             return
-        live = [(s, self.scheduler.slots[s].req.rid, int(steps[s]))
-                for s in decoding] if self.tracer.enabled else []
-        tables = self.cache.tables_device()
-        keys_dev = jnp.stack(keys)
-        zeros = jnp.zeros(n, jnp.int32)
-        draft_toks = np.zeros((n, k_max), np.int32)
-        cur = toks
-        m.counter("spec.steps").inc()
         with self._phase("spec.draft_s", "spec_draft"):
             for j in range(k_max):
-                q1 = (k_rows > j).astype(np.int32)
-                m.counter("step.model_dispatches").inc()
-                self._record_cost(self.cost_model.draft(
-                    n, keep_slices=self.config.draft_slices))
-                logits, tree = self._draft(
-                    self.params, {"tokens": jnp.asarray(cur)[:, None]},
-                    self.cache.tree, jnp.asarray(idxs + j),
-                    jnp.asarray(q1), zeros, tables)
-                self.cache.tree = tree
-                d = np.asarray(sample_step(logits, keys_dev,
-                                           jnp.asarray(steps + j),
-                                           jnp.asarray(temps)))
-                draft_toks[:, j] = d
-                cur = d
+                with self._phase("step.build_batch_s", "build_batch"):
+                    q1 = (k_rows > j).astype(np.int32)
+                    m.counter("step.model_dispatches").inc()
+                    self._record_cost(self.cost_model.draft(
+                        n, keep_slices=self.config.draft_slices))
+                    batch = {"tokens": jnp.asarray(cur)[:, None]}
+                    args = (jnp.asarray(idxs + j), jnp.asarray(q1), zeros,
+                            tables)
+                with self._phase("spec.draft_dispatch_s",
+                                 "draft_dispatch") as span:
+                    self._dispatch_counts(span, idxs + j, q1, n)
+                    logits, tree = self._draft(self.params, batch,
+                                               self.cache.tree, *args)
+                    self.cache.tree = tree
+                with self._phase("step.sample_host_s", "sample_host"):
+                    d = np.asarray(sample_step(logits, keys_dev,
+                                               jnp.asarray(steps + j),
+                                               jnp.asarray(temps)))
+                    draft_toks[:, j] = d
+                    cur = d
         s_v = k_max + 1
-        btoks = np.zeros((n, s_v), np.int32)
-        btoks[:, 0] = toks
-        btoks[:, 1:] = draft_toks
-        q_lens = np.zeros(n, np.int32)
-        for s in decoding:
-            q_lens[s] = k_rows[s] + 1
-        m.counter("step.model_dispatches").inc()
-        self._record_cost(self.cost_model.verify(n, s_v))
-        with self._phase("spec.verify_s", "spec_verify"):
-            logits, tree = self._verify(
-                self.params, {"tokens": jnp.asarray(btoks)},
-                self.cache.tree, jnp.asarray(idxs), jnp.asarray(q_lens),
-                tables)
+        with self._phase("step.build_batch_s", "build_batch"):
+            btoks = np.zeros((n, s_v), np.int32)
+            btoks[:, 0] = toks
+            btoks[:, 1:] = draft_toks
+            q_lens = np.zeros(n, np.int32)
+            for s in decoding:
+                q_lens[s] = k_rows[s] + 1
+            m.counter("step.model_dispatches").inc()
+            self._record_cost(self.cost_model.verify(n, s_v))
+            batch = {"tokens": jnp.asarray(btoks)}
+            args = (jnp.asarray(idxs), jnp.asarray(q_lens), tables)
+        with self._phase("spec.verify_s", "spec_verify") as span:
+            self._dispatch_counts(span, idxs, q_lens, n)
+            logits, tree = self._verify(self.params, batch, self.cache.tree,
+                                        *args)
             self.cache.tree = tree
         if m.enabled:
             with self._phase("step.device_sync_s", "device_sync"):
@@ -1087,27 +1180,28 @@ class ContinuousBatchingEngine:
                 logits.reshape(n * s_v, -1), flat_keys,
                 jnp.asarray(flat_steps.reshape(-1)),
                 jnp.asarray(np.repeat(temps, s_v)))).reshape(n, s_v)
-        accepted: Dict[int, np.ndarray] = {}
-        for s in decoding:
-            k_r = int(k_rows[s])
-            a = 0
-            while a < k_r and draft_toks[s, a] == targets[s, a]:
-                a += 1
-            accepted[s] = targets[s, :a + 1]
-        m.counter("spec.proposed").inc(int(k_rows.sum()))
-        m.counter("spec.accepted").inc(
-            sum(len(v) - 1 for v in accepted.values()))
-        m.counter("spec.tokens").inc(
-            sum(len(v) for v in accepted.values()))
-        self.scheduler.record_spec(accepted)
-        for slot, rid, step in live:
-            got = len(accepted[slot])
-            self.tracer.event(tr.SPEC_ACCEPT, rid, slot=slot,
-                              proposed=int(k_rows[slot]),
-                              accepted=got - 1, tokens=got)
-            for j in range(got):
-                self.tracer.event(tr.DECODE_STEP, rid, slot=slot,
-                                  step=step + j)
+        with self._phase("step.record_s", "record"):
+            accepted: Dict[int, np.ndarray] = {}
+            for s in decoding:
+                k_r = int(k_rows[s])
+                a = 0
+                while a < k_r and draft_toks[s, a] == targets[s, a]:
+                    a += 1
+                accepted[s] = targets[s, :a + 1]
+            m.counter("spec.proposed").inc(int(k_rows.sum()))
+            m.counter("spec.accepted").inc(
+                sum(len(v) - 1 for v in accepted.values()))
+            m.counter("spec.tokens").inc(
+                sum(len(v) for v in accepted.values()))
+            self.scheduler.record_spec(accepted)
+            for slot, rid, step in live:
+                got = len(accepted[slot])
+                self.tracer.event(tr.SPEC_ACCEPT, rid, slot=slot,
+                                  proposed=int(k_rows[slot]),
+                                  accepted=got - 1, tokens=got)
+                for j in range(got):
+                    self.tracer.event(tr.DECODE_STEP, rid, slot=slot,
+                                      step=step + j)
 
 
 # ---------------------------------------------------------------------------

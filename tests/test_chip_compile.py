@@ -8,6 +8,7 @@ results or speed. The topology is described inside a fixture (never at
 import) and the tests skip where it cannot be described.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -48,6 +49,11 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
+def _kernel_op(name, hlo_text):
+    """The custom-call instruction named ``name`` (``%name.N = ...``)."""
+    return re.search(rf"%{name}(\.\d+)? = \S+ custom-call\(", hlo_text)
+
+
 def _compile(fn, one_chip, *shapes):
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
     return jax.jit(fn).lower(*args).compile()
@@ -66,6 +72,8 @@ def test_paged_attention_compiles_for_v5e(one_chip, sq):
         ((n_blocks, BS), jnp.int32), ((B, NB), jnp.int32),
         ((B,), jnp.int32), ((B,), jnp.int32))
     assert "tpu_custom_call" in compiled.as_text()
+    # the kernel's pinned name is what a device trace finds it by
+    assert _kernel_op("_paged_attention_pallas", compiled.as_text())
 
 
 @pytest.mark.parametrize("k,n", SMOLLM_GEMMS,
@@ -81,3 +89,4 @@ def test_swis_matmul_compiles_for_v5e(one_chip, k, n):
         ((k // group, n, (n_shifts + 1) // 2), jnp.uint8),
         ((1, n), jnp.float32))
     assert "tpu_custom_call" in compiled.as_text()
+    assert _kernel_op("swis_matmul_packed", compiled.as_text())
