@@ -32,6 +32,7 @@ import numpy as np
 
 from repro.configs.base import ArchConfig
 from repro.core.swis import QuantConfig
+from repro.kernels.paged_attention import live_tiles, tile_pages
 from repro.models import params as pp
 from repro.models.model import Model
 from repro.serve import trace as tr
@@ -280,6 +281,14 @@ class ContinuousBatchingEngine:
                 "pallas" if jax.default_backend() == "tpu" else "xla")
         else:
             self.paged_impl = None
+        # the Pallas kernel's K/V tile (keys, tiles per table row), for the
+        # kv_tiles count of the dispatch spans
+        self._kv_tile = None
+        if self.paged_impl in ("pallas", "pallas_interpret"):
+            nb, bs = self.cache.blocks_per_slot, self.cache.block_size
+            pages = tile_pages(nb, bs, self.cfg.n_kv_heads,
+                               self.cfg.head_dim, cache_dtype)
+            self._kv_tile = (pages * bs, -(-nb // pages))
         # analytical per-dispatch cost model (costmodel.py): every model
         # launch records predicted FLOPs / HBM bytes / SWIS shift-pass
         # cycles as cost.* counters + per-kind histograms. Built from the
@@ -711,20 +720,30 @@ class ContinuousBatchingEngine:
                 return
         self._run_prefill(admitted)
 
-    def _dispatch_counts(self, span, start, q_lens, n_decode: int) -> None:
+    def _dispatch_counts(self, span, start, q_lens, n_decode: int,
+                         kernel_q_lens=None) -> None:
         """Attach a launch's counts to its dispatch span, only while a
         profiler trace records. Rows ``[0, n_decode)`` are decode rows, the
         rest a chunk group's; row r feeds ``q_lens[r]`` tokens from
         position ``start[r]`` and so attends ``start[r] + q_lens[r]``
-        positions."""
+        positions. Where the launch runs the Pallas paged kernel, with
+        ``kernel_q_lens`` queries a row, ``kv_tiles`` counts the (row, K/V
+        tile) pairs the kernel visits, by its own rule."""
         if not span.is_enabled():
             return
         live = q_lens > 0
-        span.set_metadata(
+        counts = dict(
             decode_rows=int(live[:n_decode].sum()),
             chunk_rows=int(live[n_decode:].sum()),
             chunk_tokens=int(q_lens[n_decode:].sum()),
             kv_tokens=int((start + q_lens)[live].sum()))
+        if kernel_q_lens is not None and self._kv_tile is not None:
+            tk, n_tiles = self._kv_tile
+            lo, hi = live_tiles(np.asarray(start), np.asarray(kernel_q_lens),
+                                tk=tk, n_tiles=n_tiles, causal=True,
+                                window=None)
+            counts["kv_tiles"] = int((hi - lo).sum())
+        span.set_metadata(**counts)
 
     def _run_prefill(self, admitted) -> None:
         with self._phase("step.group_setup_s", "group_setup"):
@@ -1004,7 +1023,7 @@ class ContinuousBatchingEngine:
                     jnp.asarray(last_idx), jnp.asarray(tables))
             batch = {"tokens": jnp.asarray(btoks)}
         with self._phase("step.mixed_dispatch_s", "mixed_dispatch") as span:
-            self._dispatch_counts(span, start, q_lens, n)
+            self._dispatch_counts(span, start, q_lens, n, q_lens)
             logits, tree = self._mixed(self.params, batch, self.cache.tree,
                                        *args)
             self.cache.tree = tree
@@ -1063,7 +1082,10 @@ class ContinuousBatchingEngine:
             if span.is_enabled():
                 q1 = np.zeros(self.n_slots, np.int32)
                 q1[decoding] = 1
-                self._dispatch_counts(span, idxs, q1, self.n_slots)
+                # the decode step gives the kernel no query counts: every
+                # row, idle ones too, has one query
+                self._dispatch_counts(span, idxs, q1, self.n_slots,
+                                      np.ones(self.n_slots, np.int32))
             logits, tree = self._decode(self.params, *args)
             self.cache.tree = tree
         if m.enabled:
@@ -1139,7 +1161,7 @@ class ContinuousBatchingEngine:
                             tables)
                 with self._phase("spec.draft_dispatch_s",
                                  "draft_dispatch") as span:
-                    self._dispatch_counts(span, idxs + j, q1, n)
+                    self._dispatch_counts(span, idxs + j, q1, n, q1)
                     logits, tree = self._draft(self.params, batch,
                                                self.cache.tree, *args)
                     self.cache.tree = tree
@@ -1162,7 +1184,7 @@ class ContinuousBatchingEngine:
             batch = {"tokens": jnp.asarray(btoks)}
             args = (jnp.asarray(idxs), jnp.asarray(q_lens), tables)
         with self._phase("spec.verify_s", "spec_verify") as span:
-            self._dispatch_counts(span, idxs, q_lens, n)
+            self._dispatch_counts(span, idxs, q_lens, n, q_lens)
             logits, tree = self._verify(self.params, batch, self.cache.tree,
                                         *args)
             self.cache.tree = tree

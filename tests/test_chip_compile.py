@@ -3,7 +3,7 @@
 The TPU compiler is installed without a chip attached: a described
 ``v5e:2x2`` topology lets ``jit(...).lower(shapes).compile()`` raise what
 the chip's compiler would (block-shape rules, VMEM limits, Mosaic
-lowering), at smollm-135m widths. Nothing runs, so these say nothing about
+lowering), at smollm-135m widths and, for the paged kernel, phi3-mini's. Nothing runs, so these say nothing about
 results or speed. The topology is described inside a fixture (never at
 import) and the tests skip where it cannot be described.
 """
@@ -73,6 +73,27 @@ def test_paged_attention_compiles_for_v5e(one_chip, sq):
         ((B,), jnp.int32), ((B,), jnp.int32))
     assert "tpu_custom_call" in compiled.as_text()
     # the kernel's pinned name is what a device trace finds it by
+    assert _kernel_op("_paged_attention_pallas", compiled.as_text())
+
+
+# phi3-mini's fused chunk step (4 slots and one chunk row of 128 queries,
+# 32 heads of 96, 2048-token tables) and its S=1 decode step
+PHI3_PAGED = {"chunk": (5, 128), "decode": (4, 1)}
+
+
+@pytest.mark.parametrize("step", sorted(PHI3_PAGED))
+def test_paged_attention_compiles_at_phi3_width(one_chip, step):
+    b, sq = PHI3_PAGED[step]
+    h, dh, bs, nb, n_blocks = 32, 96, 8, 256, 1025
+    compiled = _compile(
+        lambda q, k, v, pos, t, qp, ql: paged_attention_decode(
+            q, k, v, pos, t, qp, q_lens=ql, impl="pallas"),
+        one_chip,
+        ((b, sq, h, dh), jnp.bfloat16),
+        ((n_blocks, bs, h, dh), jnp.bfloat16),
+        ((n_blocks, bs, h, dh), jnp.bfloat16),
+        ((n_blocks, bs), jnp.int32), ((b, nb), jnp.int32),
+        ((b,), jnp.int32), ((b,), jnp.int32))
     assert _kernel_op("_paged_attention_pallas", compiled.as_text())
 
 

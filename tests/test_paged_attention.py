@@ -140,6 +140,80 @@ def test_unknown_impl_rejected(rng):
 
 
 # ---------------------------------------------------------------------------
+# the kernel's iteration space: live extent, many pages per tile, query
+# sub-tiles — each case against the materialized gather reference
+# ---------------------------------------------------------------------------
+
+# (bs, nb, hkv, g, dh, sq, causal, window). With 64-token blocks a K/V tile
+# is 4 blocks and a row's table 2 tiles; with 8-token blocks the table is
+# one tile of 6 blocks.
+EXTENT_CASES = {
+    "g1-dh96": (64, 6, 2, 1, 96, 4, True, None),
+    "g3-dh64": (64, 6, 2, 3, 64, 4, True, None),
+    "g3-dh96-decode": (64, 6, 2, 3, 96, 1, True, None),
+    "g1-dh64-one-tile": (8, 6, 2, 1, 64, 4, True, None),
+    "window": (64, 6, 2, 1, 64, 4, True, 40),
+    "noncausal": (64, 6, 2, 3, 64, 4, False, None),
+}
+
+
+def _mixed_arena(rng, bs, nb, hkv, g, dh, sq):
+    """Five rows of a fused step: an idle row (no queries, all-trash
+    table), decode rows (one query) and chunk rows (sq - 1 and sq
+    queries), whose extents end mid-block and, at 64-token blocks, across
+    a tile boundary. Every row's K/V is written up to its last query, then
+    two stale entries past it (rejected drafts); block 0 holds garbage
+    positions; the tables' tails are trash."""
+    cap = nb * bs
+    rows = [(0, 0), (int(0.34 * cap), 1), (int(0.52 * cap), sq - 1),
+            (int(0.66 * cap), sq), (int(0.78 * cap), 1)]
+    n_blocks = 1 + len(rows) * nb
+    pos = np.full((n_blocks, bs), -1, np.int32)
+    pos[0] = rng.integers(0, cap, (bs,))
+    tables = np.zeros((len(rows), nb), np.int32)
+    free = list(rng.permutation(np.arange(1, n_blocks)))
+    for r, (qp, ql) in enumerate(rows):
+        if ql == 0:
+            continue
+        n_tok = min(qp + ql + 2, cap)
+        for j in range(-(-n_tok // bs)):
+            blk = tables[r, j] = free.pop()
+            filled = min(bs, n_tok - j * bs)
+            pos[blk, :filled] = np.arange(j * bs, j * bs + filled)
+    q = rng.normal(0, 1, (len(rows), sq, hkv * g, dh))
+    k = rng.normal(0, 1, (n_blocks, bs, hkv, dh))
+    v = rng.normal(0, 1, (n_blocks, bs, hkv, dh))
+    q_pos, q_lens = np.array(rows, np.int32).T
+    return (jnp.asarray(q, jnp.float32), jnp.asarray(k, jnp.float32),
+            jnp.asarray(v, jnp.float32), jnp.asarray(pos),
+            jnp.asarray(tables), jnp.asarray(q_pos), jnp.asarray(q_lens))
+
+
+@pytest.mark.parametrize("case", list(EXTENT_CASES))
+@pytest.mark.parametrize("impl", ["pallas_interpret", "xla"])
+def test_live_extent_parity_vs_gather(rng, impl, case):
+    bs, nb, hkv, g, dh, sq, causal, window = EXTENT_CASES[case]
+    q, k, v, pos, tables, q_pos, q_lens = _mixed_arena(
+        rng, bs, nb, hkv, g, dh, sq)
+    b = tables.shape[0]
+    gk = k[tables].reshape((b, nb * bs, hkv, dh))
+    gv = v[tables].reshape((b, nb * bs, hkv, dh))
+    gp = jnp.where((tables == 0)[:, :, None], -1,
+                   pos[tables]).reshape(b, nb * bs)
+    want = np.asarray(full_attention(
+        q, gk, gv, q_pos=q_pos[:, None] + jnp.arange(sq)[None, :],
+        kv_pos=gp, causal=causal, window=window))
+    got = np.asarray(paged_attention_decode(
+        q, k, v, pos, tables, q_pos, q_lens=q_lens, causal=causal,
+        window=window, impl=impl))
+    real = np.arange(sq)[None, :] < np.asarray(q_lens)[:, None]
+    np.testing.assert_allclose(got[real], want[real], rtol=1e-5, atol=1e-5)
+    if impl == "pallas_interpret":
+        # the kernel finalizes padding queries and idle rows to zeros
+        assert not np.any(got[~real])
+
+
+# ---------------------------------------------------------------------------
 # engine-level parity: fused decode vs the gather engine, token-exact
 # ---------------------------------------------------------------------------
 

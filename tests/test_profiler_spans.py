@@ -226,3 +226,41 @@ def test_step_programs_carry_the_model_scopes(packed):
         # each scope names its own work: no op is in two of them
         for outer in ("attention", "kv_commit", "norm", "embed"):
             assert f"/{outer}/gemm/" not in text, outer
+
+
+def test_dispatch_spans_count_the_kernels_kv_tiles(rng, tmp_path):
+    """With the Pallas paged kernel, each fused and decode span's
+    ``kv_tiles`` is the (row, K/V tile) pairs the kernel visits for the
+    launch's start and query counts: a row walks the tiles up to the one
+    holding its last query, and a fused step's idle rows walk none. The
+    decode step gives the kernel no query counts, so each of its rows has
+    one. Blocks of 64 tokens make a tile 4 blocks (256 keys), so prompts
+    of 280 tokens end in a table's second tile."""
+    cfg, params = _setup()
+    eng = ContinuousBatchingEngine(cfg, params, config=EngineConfig(
+        max_len=320, n_slots=2, block_size=64, prefill_chunk=64,
+        fused_step=True, use_paged_kernel=True,
+        paged_impl="pallas_interpret"))
+    assert eng._kv_tile == (256, 2)
+    launches = []
+
+    def record(fn, decode):
+        def call(*args):
+            start = np.asarray(args[3])
+            q = np.ones_like(start) if decode else np.asarray(args[4])
+            tiles = np.minimum(-(-(start + q) // 256), 2)
+            launches.append(int(np.where(q > 0, tiles, 0).sum()))
+            return fn(*args)
+        return call
+
+    eng._mixed = record(eng._mixed, decode=False)
+    eng._decode = record(eng._decode, decode=True)
+    events = _traced(eng, lambda: _serve(eng, rng, n_req=2, prompt=280,
+                                         tokens=3), tmp_path)
+    spans = [int(stats["kv_tiles"]) for name, _, _, stats in events
+             if name in ("serve.mixed_dispatch", "serve.decode_dispatch")]
+    assert spans == launches
+    assert max(spans) >= 3  # a second tile was walked
+    mixed = [int(stats["kv_tiles"]) for name, _, _, stats in events
+             if name == "serve.mixed_dispatch"]
+    assert min(mixed) == 1  # one chunk row alone: its idle rows walk none
