@@ -167,7 +167,7 @@ class RunData:
     win: Window
     rows: list  # per launch in the traced part: (kind, [(start, n_new)])
     trace: object  # trace.Reduced of the traced part
-    shapes: object  # counts.Shapes of the configuration
+    shapes: object  # the configuration family's shapes(cfg)
     peak: dict
     events: list  # the engine's own request events: (kind, rid, ts)
     traced: tuple  # host-clock (start, end) of the traced part

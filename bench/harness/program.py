@@ -2,10 +2,12 @@
 program's ``ContinuousBatchingEngine`` in its production mode (fused
 chunked prefill, the paged Pallas attention kernel, bf16 KV arena).
 
-This is the only module of the benchmark that imports the program. It
-builds the program's parameter tree from the seed (``weights.draw_tree``),
-the engine from the configuration's deployment fields, records what each
-step program was asked to do, and warms every shape the traffic uses.
+This is the only module of the benchmark that imports the program, but
+for a family's ``arch_config``, which imports the program's config types
+after this module has put the program on the path. It builds the program's parameter tree from the seed
+(``weights.draw_tree``), the engine from the configuration's deployment
+fields, records what each step program was asked to do, and warms every
+shape the traffic uses.
 """
 from __future__ import annotations
 
@@ -24,8 +26,7 @@ _SRC = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
 if _SRC not in sys.path:
     sys.path.insert(0, _SRC)
 
-from repro.configs.base import ArchConfig, QuantPolicy  # noqa: E402
-from repro.core.swis import QuantConfig  # noqa: E402
+from repro.configs.base import ArchConfig  # noqa: E402
 from repro.models.model import Model  # noqa: E402
 from repro.models.params import is_placeholder  # noqa: E402
 from repro.serve import (ContinuousBatchingEngine, EngineConfig,  # noqa: E402
@@ -33,26 +34,8 @@ from repro.serve import (ContinuousBatchingEngine, EngineConfig,  # noqa: E402
 from repro.serve import engine as engine_mod  # noqa: E402
 from repro.serve.quantized import pack_placeholders  # noqa: E402
 
+from bench.harness import spec  # noqa: E402
 from bench.harness import weights as W  # noqa: E402
-
-
-def arch_config(cfg: dict) -> ArchConfig:
-    """The program's config for a configuration file."""
-    arch = ArchConfig(
-        name=cfg["name"], family="dense",
-        n_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
-        n_heads=cfg["num_attention_heads"],
-        n_kv_heads=cfg["num_key_value_heads"],
-        d_ff=cfg["intermediate_size"], vocab=cfg["vocab_size"],
-        rope_theta=cfg["rope_theta"],
-        tie_embeddings=cfg["tie_word_embeddings"],
-        param_dtype="bfloat16", compute_dtype="bfloat16")
-    if cfg.get("swis"):
-        sw = cfg["swis"]
-        arch = arch.replace(quant=QuantPolicy(cfg=QuantConfig(
-            method=sw["method"], n_shifts=sw["n_shifts"],
-            group_size=sw["group_size"]), mode="off"))
-    return arch
 
 
 def param_spec(arch: ArchConfig, swis: bool):
@@ -187,8 +170,11 @@ class Recorder:
         return out
 
 
-def build(cfg: dict, deploy: dict, seed: int):
-    arch = arch_config(cfg)
+def build(cfg: dict, deploy: dict, seed: int, family=None):
+    """The engine and its recorder; ``family``: the configuration's
+    family module (``spec.family``), looked up where not given."""
+    family = family or spec.family(cfg)
+    arch = family.arch_config(cfg)
     params = draw_params(cfg, arch, seed)
     engine = ContinuousBatchingEngine(arch, params, config=engine_config(
         deploy))

@@ -1,12 +1,10 @@
-"""Plain float32 reference of the served model, and its control.
+"""The parts of the float32 reference that every model family shares,
+and the comparison that decides ``correct``.
 
-A pre-norm decoder (RMSNorm, RoPE over half-split pairs, causal softmax
-attention, SwiGLU MLP, untied unembedding), written from the published
-architecture with ``jax.numpy`` alone: no kernel, cache or batching of the
-program, and nothing the program made. Weights are drawn again from the
-seed, one layer at a time (``weights.layer_leaf``), so the reference fits
-beside nothing else on the chip. SWIS layers are decoded from their bit
-planes by :func:`swis_decode`, written here from the storage format.
+A family's ``Reference`` (``bench/families/<model_type>.py``) writes its
+model from the published architecture with ``jax.numpy`` alone, with the
+matmul :func:`_mm` in either precision and SWIS layers decoded from their
+bit planes by :func:`swis_decode`, written here from the storage format.
 
 ``precision="f32"`` runs every matmul in float32 at HIGHEST precision.
 ``precision="fp8"`` is the control: every matmul operand is rounded to
@@ -16,19 +14,11 @@ tokens and the logits they were sampled from.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench.harness import weights as W
-
 HIGHEST = jax.lax.Precision.HIGHEST
-LAYER_PREFIX = "blocks/sub0_attn"
-GEMMS = ("attn/wq/w", "attn/wk/w", "attn/wv/w", "attn/wo/w",
-         "mlp/wi/w", "mlp/wg/w", "mlp/wo/w")
-NORMS = ("ln1/scale", "ln2/scale")
 
 
 def _fp8(a):
@@ -64,124 +54,6 @@ def swis_decode(planes, group: int):
     return sign * mag * planes["scale"].astype(jnp.float32)
 
 
-class Reference:
-    """The reference for one configuration file (``cfg``: its dict)."""
-
-    def __init__(self, cfg: dict, seed: int):
-        self.cfg = cfg
-        self.d = cfg["hidden_size"]
-        self.h = cfg["num_attention_heads"]
-        self.hkv = cfg["num_key_value_heads"]
-        self.dh = self.d // self.h
-        self.f = cfg["intermediate_size"]
-        self.vocab = cfg["vocab_size"]
-        self.n_layers = cfg["num_hidden_layers"]
-        self.eps = cfg["rms_norm_eps"]
-        self.theta = cfg["rope_theta"]
-        self.swis = cfg.get("swis")
-        self.root = W.root_key(seed)
-
-    # -- weights --------------------------------------------------------
-
-    def _gemm_shape(self, name):
-        d, f, q, kv = self.d, self.f, self.h * self.dh, self.hkv * self.dh
-        return {"attn/wq/w": (d, q), "attn/wk/w": (d, kv),
-                "attn/wv/w": (d, kv), "attn/wo/w": (q, d),
-                "mlp/wi/w": (d, f), "mlp/wg/w": (d, f),
-                "mlp/wo/w": (f, d)}[name]
-
-    @functools.partial(jax.jit, static_argnums=0)
-    def layer_weights(self, layer):
-        out = {}
-        for name in GEMMS:
-            path = f"{LAYER_PREFIX}/{name}"
-            k, c = self._gemm_shape(name)
-            if self.swis:
-                sw = self.swis
-                planes = W.layer_leaf(self.root, path, layer, lambda key: (
-                    W.swis_planes(key, k, c, sw["n_shifts"],
-                                  sw["group_size"])))
-                out[name] = swis_decode(planes, sw["group_size"])
-            else:
-                out[name] = W.layer_leaf(
-                    self.root, path, layer,
-                    lambda key: W.dense_leaf(key, "w", (k, c), self.vocab)
-                ).astype(jnp.float32)
-        for name in NORMS:
-            out[name] = W.layer_leaf(
-                self.root, f"{LAYER_PREFIX}/{name}", layer,
-                lambda key: W.dense_leaf(key, "scale", (self.d,),
-                                         self.vocab)).astype(jnp.float32)
-        return out
-
-    def _global(self, path, shape):
-        name = path.rsplit("/", 1)[-1]
-        return W.dense_leaf(W.leaf_key(self.root, path), name, shape,
-                            self.vocab).astype(jnp.float32)
-
-    # -- forward --------------------------------------------------------
-
-    def _rms(self, x, scale):
-        var = jnp.mean(x * x, axis=-1, keepdims=True)
-        return x * jax.lax.rsqrt(var + self.eps) * scale
-
-    def _rope(self, x, pos):  # x (S, H, Dh), pos (S,)
-        half = self.dh // 2
-        freqs = 1.0 / (self.theta ** (jnp.arange(half, dtype=jnp.float32)
-                                      / half))
-        ang = pos[:, None].astype(jnp.float32) * freqs
-        cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
-        x1, x2 = x[..., :half], x[..., half:]
-        return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                               axis=-1)
-
-    @functools.partial(jax.jit, static_argnums=(0, 3))
-    def _layer(self, x, w, precision):
-        """One decoder layer over a batch of sequences (B, S, d)."""
-        s = x.shape[1]
-        pos = jnp.arange(s)
-        causal = pos[None, :] <= pos[:, None]
-        g = self.h // self.hkv
-
-        def one(xr):  # (S, d)
-            h = self._rms(xr, w["ln1/scale"])
-            q = _mm("sd,de->se", h, w["attn/wq/w"], precision)
-            k = _mm("sd,de->se", h, w["attn/wk/w"], precision)
-            v = _mm("sd,de->se", h, w["attn/wv/w"], precision)
-            q = self._rope(q.reshape(s, self.h, self.dh), pos)
-            k = self._rope(k.reshape(s, self.hkv, self.dh), pos)
-            v = v.reshape(s, self.hkv, self.dh)
-            k = jnp.repeat(k, g, axis=1)
-            v = jnp.repeat(v, g, axis=1)
-            sc = _mm("qhd,khd->hqk", q, k, precision) / np.sqrt(self.dh)
-            sc = jnp.where(causal[None], sc, -jnp.inf)
-            p = jax.nn.softmax(sc, axis=-1)
-            o = _mm("hqk,khd->qhd", p, v, precision).reshape(s, -1)
-            xr = xr + _mm("sd,de->se", o, w["attn/wo/w"], precision)
-            h = self._rms(xr, w["ln2/scale"])
-            a = jax.nn.silu(_mm("sd,df->sf", h, w["mlp/wi/w"], precision))
-            b = _mm("sd,df->sf", h, w["mlp/wg/w"], precision)
-            return xr + _mm("sf,fd->sd", a * b, w["mlp/wo/w"], precision)
-
-        return jax.lax.map(one, x)
-
-    def final_hidden(self, tokens: np.ndarray, precision: str = "f32"):
-        """tokens (B, S) int -> normalized final hidden states (B, S, d)
-        float32. Padding after a sequence's end does not reach its
-        positions (causal)."""
-        emb = self._global("embed/tok", (self.vocab, self.d))
-        x = jnp.take(emb, jnp.asarray(tokens), axis=0)
-        del emb
-        for layer in range(self.n_layers):
-            x = self._layer(x, self.layer_weights(layer), precision)
-        return self._rms(x, self._global("final_norm/scale", (self.d,)))
-
-    def logits(self, hidden, precision: str = "f32"):
-        """(..., d) -> (..., vocab) float32, in the given precision."""
-        un = self._global("embed/unembed", (self.d, self.vocab))
-        return _mm("...d,dv->...v", hidden, un, precision)
-
-
 @jax.jit
 def _gap_rows(logits, tokens):
     """Reference's best logit minus its logit of ``tokens`` (per row)."""
@@ -198,11 +70,11 @@ def _rel_err_rows(got, logits):
             / jnp.linalg.norm(logits, axis=-1))
 
 
-def widest_gap(ref: Reference, seqs, *, pad_len: int, pad_seqs: int,
+def widest_gap(ref, seqs, *, pad_len: int, pad_seqs: int,
                control: bool = False, logits=None,
                rows_per_block: int = 512) -> dict:
     """Compare served tokens, and the logits they were sampled from, with
-    the reference.
+    the reference ``ref``, a family's ``Reference``.
 
     ``seqs``: list of ``(prompt, served)`` int arrays. Position ``p`` of
     ``prompt + served[:-1]`` predicts ``served[p - len(prompt) + 1]``. For

@@ -1,11 +1,14 @@
 """Finds a cell's pieces by the names in ``BENCHMARK.json``:
-``bench/configs/<config>.json``, ``bench/traffic/<traffic>.json`` and one
-reader ``bench/metrics/<metric>.py`` per per-layer metric."""
+``bench/configs/<config>.json``, ``bench/traffic/<traffic>.json``, one
+reader ``bench/metrics/<metric>.py`` per per-layer metric, and the model
+family ``bench/families/<model_type>.py`` that the configuration's
+published ``model_type`` names."""
 from __future__ import annotations
 
 import importlib.util
 import json
 import os
+import sys
 from dataclasses import dataclass
 from typing import Callable, Dict, List
 
@@ -51,14 +54,32 @@ def load_cell(name: str, root: str = ROOT) -> Cell:
     return Cell(name, w["chips"], cfg, mix, deploy, e2e, layer)
 
 
+def _module(path: str, name: str):
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod  # dataclasses look their module up
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def reader(metric: str, root: str = ROOT) -> Callable:
     """The ``read(run)`` function of ``bench/metrics/<metric>.py``."""
     path = os.path.join(root, "bench", "metrics", metric + ".py")
-    spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{metric.replace('.', '_')}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _module(path, f"bench_metric_{metric.replace('.', '_')}").read
+
+
+def family(cfg: dict, root: str = ROOT):
+    """The module ``bench/families/<model_type>.py`` of a configuration,
+    with its ``arch_config``, ``Reference`` and ``shapes``."""
+    folder = os.path.join(root, "bench", "families")
+    known = sorted(f[:-3] for f in os.listdir(folder)
+                   if f.endswith(".py") and not f.startswith("_"))
+    kind = cfg.get("model_type")
+    if kind not in known:
+        raise KeyError(f"no family for model_type {kind!r} of "
+                       f"{cfg.get('name')!r}; known: {known}")
+    return _module(os.path.join(folder, kind + ".py"),
+                   f"bench_family_{kind.replace('.', '_')}")
 
 
 def readers(cell: Cell, root: str = ROOT) -> Dict[str, Callable]:

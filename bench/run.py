@@ -166,13 +166,14 @@ def main(argv=None, *, root: str = ROOT, device=None, fault=None) -> int:
 
     from bench.harness import counts, driver, program, traffic
     from bench.harness import trace as tr
-    from bench.harness.reference import Reference, widest_gap
+    from bench.harness.reference import widest_gap
 
     peak = counts.peaks(device["kind"])
     cfg, mix, deploy = cell.config, cell.mix, cell.deploy
+    family = spec.family(cfg, root)
     vocab = cfg["vocab_size"]
     t0, c0 = time.time(), clock.mark()
-    engine, rec = program.build(cfg, deploy, args.seed)
+    engine, rec = program.build(cfg, deploy, args.seed, family)
     t1, c1 = time.time(), clock.mark()
     warm = program.warm_up(engine, deploy, mix, vocab, args.seed)
     t2 = time.time()
@@ -218,7 +219,7 @@ def main(argv=None, *, root: str = ROOT, device=None, fault=None) -> int:
         red = tr.Reduced(form)
         run = driver.RunData(
             win=win, rows=rec.rows(), trace=red,
-            shapes=counts.Shapes.from_config(cfg), peak=peak,
+            shapes=family.shapes(cfg), peak=peak,
             events=[(e.kind, e.rid, e.ts) for e in engine.tracer.events()],
             traced=(traced.lo, traced.hi), cell=cell)
         for m in cell.per_layer:
@@ -251,7 +252,7 @@ def main(argv=None, *, root: str = ROOT, device=None, fault=None) -> int:
     t3 = time.time()
     limits = cfg["limits"]
     if seqs:
-        gap = widest_gap(Reference(cfg, args.seed), seqs,
+        gap = widest_gap(family.Reference(cfg, args.seed), seqs,
                          pad_len=deploy["max_len"],
                          pad_seqs=check["sample_seqs"],
                          logits=served_logits)

@@ -1,6 +1,6 @@
 """A checkout in miniature for the CPU tests: BENCHMARK.json, one smoke
 configuration per weight format, small traffic, and the real metric
-readers, under a temporary root."""
+readers and model families, under a temporary root."""
 import json
 import os
 import shutil
@@ -8,8 +8,8 @@ import shutil
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 SMOKE = {
-    "name": "smoke", "hidden_size": 64, "intermediate_size": 128,
-    "num_hidden_layers": 2, "num_attention_heads": 4,
+    "name": "smoke", "model_type": "phi3", "hidden_size": 64,
+    "intermediate_size": 128, "num_hidden_layers": 2, "num_attention_heads": 4,
     "num_key_value_heads": 4, "vocab_size": 500, "rms_norm_eps": 1e-6,
     "rope_theta": 10000.0, "tie_word_embeddings": False,
     "deployment": {"n_slots": 4, "max_len": 128, "n_cache_blocks": 0,
@@ -72,13 +72,15 @@ def benchmark_json(bench_file: str) -> dict:
 
 
 def make_root(tmp) -> str:
-    """A miniature checkout under ``tmp``; the metric readers are the
-    real ones."""
+    """A miniature checkout under ``tmp``; the metric readers and the
+    model families are the real ones."""
     root = str(tmp)
     os.makedirs(os.path.join(root, "bench", "configs"))
     os.makedirs(os.path.join(root, "bench", "traffic"))
-    shutil.copytree(os.path.join(BENCH, "metrics"),
-                    os.path.join(root, "bench", "metrics"))
+    for folder in ("metrics", "families"):
+        shutil.copytree(os.path.join(BENCH, folder),
+                        os.path.join(root, "bench", folder),
+                        ignore=shutil.ignore_patterns("__pycache__"))
     real = os.path.join(os.path.dirname(BENCH), "BENCHMARK.json")
     bench = benchmark_json(real)
     # the real metrics, pointed at the smoke cells of the same traffic,

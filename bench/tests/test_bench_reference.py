@@ -16,9 +16,13 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path.insert(0, ROOT)
 
+from bench.harness import spec  # noqa: E402
 from bench.harness import weights as W  # noqa: E402
-from bench.harness.reference import Reference, swis_decode, widest_gap  # noqa: E402
+from bench.harness.reference import swis_decode, widest_gap  # noqa: E402
 from bench.tests.smoke_cells import SMOKE, SWIS  # noqa: E402
+
+FAMILY = spec.family(SMOKE)  # the dense family (phi3) of both smoke files
+Reference = FAMILY.Reference
 
 SEED = 2 ** 33 + 5  # wider than 32 bits
 
@@ -102,11 +106,11 @@ def test_wrong_seed_fails_the_limit(served):
 def _checksum(seed):
     """Sums of every leaf of a small drawn tree and of the reference's
     layer 1."""
-    from bench.harness.program import arch_config, draw_params
+    from bench.harness.program import draw_params
 
     out = {}
     for cfg in (SMOKE, SWIS):
-        tree = draw_params(cfg, arch_config(cfg), seed)
+        tree = draw_params(cfg, FAMILY.arch_config(cfg), seed)
         leaves = jax.tree_util.tree_leaves_with_path(tree)
         out[cfg["name"]] = {jax.tree_util.keystr(k): float(
             jnp.sum(v.astype(jnp.float32))) for k, v in leaves}
@@ -131,10 +135,10 @@ def test_same_seed_same_weights_in_two_processes():
 
 
 def test_reference_layer_draw_equals_the_stacked_tree():
-    from bench.harness.program import arch_config, draw_params
+    from bench.harness.program import draw_params
 
     for cfg in (SMOKE, SWIS):
-        tree = draw_params(cfg, arch_config(cfg), SEED)
+        tree = draw_params(cfg, FAMILY.arch_config(cfg), SEED)
         blk = tree["blocks"]["sub0_attn"]
         ref = Reference(cfg, SEED)
         for layer in range(cfg["num_hidden_layers"]):

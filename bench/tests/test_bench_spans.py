@@ -210,7 +210,7 @@ def _run_data(red, form):
         cfg = json.load(f)
     return SimpleNamespace(
         win=SimpleNamespace(step_spans=steps), rows=rows, trace=red,
-        shapes=counts.Shapes.from_config(cfg),
+        shapes=spec.family(cfg).shapes(cfg),
         peak=counts.peaks("TPU v5 lite"),
         traced=(steps[0][0], steps[-1][1]), events=[], cell=None)
 

@@ -24,15 +24,15 @@ from bench import run as bench_run  # noqa: E402
 from bench.harness import spec  # noqa: E402
 
 
-def serve(cell, seeds, seconds):
+def serve(cell, family, seeds, seconds):
     """Per seed: the sampled (prompt, served) pairs, their logits rows and
     the window's numbers."""
     from bench.harness import driver, program, traffic
 
     cfg, deploy, mix = cell.config, cell.deploy, cell.mix
-    engine, rec = program.build(cfg, deploy, seeds[0])
+    engine, rec = program.build(cfg, deploy, seeds[0], family)
     program.warm_up(engine, deploy, mix, cfg["vocab_size"], seeds[0])
-    arch = program.arch_config(cfg)
+    arch = family.arch_config(cfg)
     out = {}
     for seed in seeds:
         if seed != seeds[0]:
@@ -70,17 +70,18 @@ def main():
         jax.config.update("jax_compilation_cache_dir", bench_run.CACHE_DIR)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     bench_run.device_or_exit(cell.chips)
-    from bench.harness.reference import Reference, widest_gap
+    from bench.harness.reference import widest_gap
 
     seeds = [int(s) for s in args.seeds.split(",")]
     control = {int(s) for s in args.control_seeds.split(",") if s}
-    served = serve(cell, seeds, args.seconds)
+    family = spec.family(cell.config)
+    served = serve(cell, family, seeds, args.seconds)
     gc.collect()
     kw = dict(pad_len=cell.deploy["max_len"],
               pad_seqs=cell.mix["check"]["sample_seqs"])
     for seed in seeds:
         got = served.pop(seed)
-        ref = Reference(cell.config, seed)
+        ref = family.Reference(cell.config, seed)
         t0 = time.time()
         line = {"seed": seed, "failed": got["failed"],
                 "tokens_per_s": got["tokens_per_s"],
